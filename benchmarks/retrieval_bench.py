@@ -231,14 +231,18 @@ print("PROBE_JSON:" + json.dumps(report))
 
 def _sharded_probe() -> dict:
     root = Path(__file__).resolve().parents[1]
-    env = dict(os.environ, PYTHONPATH=f"{root / 'src'}:{root}")
+    # a CPU probe on forced host devices: the parent may hold the
+    # accelerator, which one process at a time can use
+    env = dict(os.environ, PYTHONPATH=f"{root / 'src'}:{root}",
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     res = subprocess.run([sys.executable, "-c", _PROBE_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=900)
     for line in res.stdout.splitlines():
         if line.startswith("PROBE_JSON:"):
             return json.loads(line[len("PROBE_JSON:"):])
-    return {"error": (res.stderr or res.stdout)[-800:]}
+    raise RuntimeError("sharded retrieval probe failed:\n"
+                       f"{(res.stderr or res.stdout)[-800:]}")
 
 
 def _refusal_collapse_check(n_train: int = 300, n_eval: int = 100,

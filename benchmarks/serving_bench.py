@@ -363,21 +363,22 @@ def tracer_overhead_row(repeats: int = 7, n_requests: int = 400) -> dict:
 
 def _one_device_mesh():
     """A 1-device ("data","model") mesh regardless of host flags."""
-    from jax.sharding import Mesh
-    return Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
-                ("data", "model"))
+    from repro.launch.mesh import make_serving_mesh
+    return make_serving_mesh("dp=1", devices=jax.devices()[:1])
 
 
 def _sharded_probe(mesh_spec: str) -> dict:
-    """Re-exec this benchmark in a subprocess with dp*mp forced host
+    """Re-exec this benchmark in a CPU subprocess with dp*mp forced host
     devices: token parity (single-device vs sharded executor) on the
     mixed-action workload, plus the sharded decode throughput (and,
     with mp>1, an on-device check that params shard on the model
-    axis)."""
+    axis).  A probe that fails raises."""
     parts = dict(kv.split("=") for kv in mesh_spec.split(","))
     ndev = int(parts.get("dp", 1)) * int(parts.get("mp", 1))
     root = Path(__file__).resolve().parents[1]
-    env = dict(os.environ,
+    # a CPU parity probe on forced host devices: the parent may hold
+    # the accelerator, which one process at a time can use
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={ndev}",
                PYTHONPATH=f"{root / 'src'}:{root}")
     res = subprocess.run(
@@ -386,7 +387,8 @@ def _sharded_probe(mesh_spec: str) -> dict:
     for line in res.stdout.splitlines():
         if line.startswith("PROBE_JSON:"):
             return json.loads(line[len("PROBE_JSON:"):])
-    return {"mesh": mesh_spec, "error": (res.stderr or res.stdout)[-800:]}
+    raise RuntimeError(f"sharded probe {mesh_spec} failed:\n"
+                       f"{(res.stderr or res.stdout)[-800:]}")
 
 
 def probe_main(mesh_spec: str) -> None:

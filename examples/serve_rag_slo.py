@@ -24,6 +24,7 @@ from repro.configs import get_config
 from repro.core.config import TestbedConfig
 from repro.core.offline_log import build_testbed
 from repro.data.tokenizer import HashTokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.routing import (ContinuousEngineBackend, EngineBackend, Gateway,
                            MLPPolicy, Request, get_slo_profile,
@@ -51,6 +52,7 @@ def main():
     args = ap.parse_args()
     if args.mesh and args.engine != "continuous":
         ap.error("--mesh requires --engine continuous")
+    enable_compile_cache()
     profile = get_slo_profile(args.slo)
 
     print("# building testbed + routing policy ...")

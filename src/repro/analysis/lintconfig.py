@@ -31,6 +31,8 @@ VMEM_BUDGET_BYTES = 16 * 2 ** 20   # ~16 MiB VMEM per TPU core
 DEFAULT_DIM_BINDINGS: Dict[str, int] = {
     # attention / decode
     "D": 256, "Dv": 256, "block_q": 128, "block_kv": 128,
+    # decode: query heads per kv head (largest GQA group shipped: 8)
+    "G": 8,
     # paged decode: largest shipping page size
     "ps": 64,
     # dense retrieval: 128-aligned hashed-n-gram embedding, k<=64

@@ -1,20 +1,32 @@
-"""Qwen1.5-32B — dense with QKV bias. [hf:Qwen/Qwen1.5-0.5B family]"""
+"""Qwen1.5-32B — dense decoder with QKV bias and grouped-query attention.
+
+Source: the model's own published ``config.json`` (Hugging Face
+``Qwen/Qwen1.5-32B``, architecture ``Qwen2ForCausalLM``): hidden_size
+5120, 64 layers, 40 attention heads over 8 key/value heads (GQA, head
+dim 128), intermediate_size 27392, vocab_size 152064, QKV bias.
+
+Assumed — recalled from that file, which cannot be re-read offline:
+``rope_theta`` 1e6, ``rms_norm_eps`` 1e-6 and ``tie_word_embeddings``
+false (a separate LM head).
+"""
 from repro.core.config import ModelConfig
 
 FULL = ModelConfig(
     name="qwen1.5-32b",
     arch_type="dense",
-    source="hf:Qwen/Qwen1.5-0.5B",
+    source="hf:Qwen/Qwen1.5-32B/config.json",
     n_layers=64,
     d_model=5120,
     n_heads=40,
-    n_kv_heads=40,
+    n_kv_heads=8,
     head_dim=128,
     d_ff=27392,
     vocab_size=152064,
     attn_type="gqa",
     qkv_bias=True,
     rope_theta=1000000.0,
+    norm_eps=1e-6,
+    tie_embeddings=False,
     remat="full",
 )
 

@@ -1,8 +1,15 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU containers the kernels execute in interpret mode (the kernel body
-runs as traced jnp on host); on TPU they compile to Mosaic.  Block sizes
-default to MXU-aligned tiles and shrink to fit small inputs.
+On TPU the kernels compile to Mosaic; on CPU they execute in interpret
+mode (the kernel body runs as traced jnp on host).  Any other platform
+is refused rather than silently interpreted.  Block sizes default to
+MXU-aligned tiles and shrink to fit small inputs.
+
+The decode kernels also run under the sharded executor's mesh: Mosaic
+kernels cannot be partitioned automatically, so when traced under a
+mesh context they are wrapped in ``shard_map`` — slots on the batch
+axes, kv heads on ``model``.  Attention is independent per slot and
+per kv head, so each device runs the kernel on its own shard.
 """
 from __future__ import annotations
 
@@ -10,6 +17,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.bm25 import bm25_pallas
 from repro.kernels.dense_topk import _dense_topk_padded
@@ -17,10 +25,35 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.flash_decode import (flash_decode_pallas,
                                         paged_flash_decode_pallas)
 from repro.kernels.ssd_scan import ssd_scan_pallas
+from repro.sharding import batch_entry
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for TPU and run interpreted on CPU; "
+        f"platform {platform!r} is neither")
+
+
+def _decode_shards(n_slots: int, n_kv_heads: int, n_pages: int = 0):
+    """``(mesh, slot_axes, head_axis)`` for running a decode kernel per
+    shard, or None when not traced under a mesh.  Slots shard like the
+    slot cache (and only when the page pool shards the same way, so a
+    slot's pages are local); kv heads shard on ``model`` when it
+    divides them.  An axis that does not apply is None (replicated)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return None
+    slot, _ = batch_entry(n_slots, mesh)
+    if n_pages and batch_entry(n_pages, mesh)[0] != slot:
+        slot = None
+    mp = dict(mesh.shape).get("model", 1)
+    head = "model" if mp > 1 and n_kv_heads % mp == 0 else None
+    return mesh, slot, head
 
 
 @partial(jax.jit, static_argnames=("k1", "b"))
@@ -102,25 +135,35 @@ def flash_decode(q, k, v, lengths, *, block_kv: int = 128):
 
     q: (B, H, D) — one query per slot; k/v: (B, L, Hkv, D[v]) — the
     full-length slot cache; lengths: (B,) valid kv length per slot
-    (>= 1).  Returns (B, H, Dv).  The GQA head->kv-head mapping happens
-    inside the kernel's BlockSpec index map, so the grouped cache is
-    only transposed to kv-head-major — never expanded; block_kv shrinks
-    to the largest divisor of L so ragged cache lengths still tile.
+    (>= 1).  Returns (B, H, Dv).  The kernel groups the query heads of
+    each kv head in one tile, so the grouped cache is only transposed
+    to kv-head-major — never expanded; a cache length that block_kv
+    does not divide is padded to full blocks and masked.
     """
-    B, H, D = q.shape
     L = k.shape[1]
-    Dv = v.shape[-1]
-    kf = k.transpose(0, 2, 1, 3)                  # (B, Hkv, L, D)
-    vf = v.transpose(0, 2, 1, 3)
     bk = min(block_kv, L)
     pad = -L % bk
-    if pad:
-        # keep full-width kv blocks for any cache length; the padded
-        # tail is masked by the kernel's length check
-        kf = jnp.pad(kf, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        vf = jnp.pad(vf, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    return flash_decode_pallas(q, kf, vf, jnp.maximum(lengths, 1),
-                               block_kv=bk, interpret=_interpret())
+
+    def local(q, k, v, lengths):
+        kf = k.transpose(0, 2, 1, 3)              # (B, Hkv, L, D)
+        vf = v.transpose(0, 2, 1, 3)
+        if pad:
+            # keep full-width kv blocks for any cache length; the padded
+            # tail is masked by the kernel's length check
+            kf = jnp.pad(kf, ((0, 0), (0, 0), (0, pad), (0, 0)))
+            vf = jnp.pad(vf, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        return flash_decode_pallas(q, kf, vf, jnp.maximum(lengths, 1),
+                                   block_kv=bk, interpret=_interpret())
+
+    shards = _decode_shards(q.shape[0], k.shape[2])
+    if shards is None:
+        return local(q, k, v, lengths)
+    mesh, s, h = shards
+    kv = P(s, None, h, None)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(s, h, None), kv, kv, P(s)),
+                         out_specs=P(s, h, None), check_vma=False)(
+        q, k, v, lengths)
 
 
 @jax.jit
@@ -131,17 +174,35 @@ def paged_flash_decode(q, k_pages, v_pages, table, lengths):
     page_size, Hkv, D[v]) — the executor's global page pools; table:
     (B, max_blocks) int32 page ids per slot; lengths: (B,) valid kv
     length (>= 1).  Returns (B, H, Dv).  Pools transpose to
-    kv-head-major (page-local — never gathered to a contiguous row on
-    the host side); table entries clamp into range so unallocated tail
-    blocks read a valid page and are masked by the length check.
+    kv-head-major (page-local — never gathered to a contiguous row);
+    table entries clamp into range, and the kernel never reads blocks
+    past a slot's length.
     """
-    NP = k_pages.shape[0]
-    kf = k_pages.transpose(0, 2, 1, 3)            # (NP, Hkv, ps, D)
-    vf = v_pages.transpose(0, 2, 1, 3)
-    tab = jnp.clip(table.astype(jnp.int32), 0, NP - 1)
-    return paged_flash_decode_pallas(q, kf, vf, tab,
-                                     jnp.maximum(lengths, 1),
-                                     interpret=_interpret())
+    shards = _decode_shards(q.shape[0], k_pages.shape[2], k_pages.shape[0])
+
+    def local(q, k_pages, v_pages, table, lengths):
+        NP = k_pages.shape[0]
+        tab = table.astype(jnp.int32)
+        if shards is not None and shards[1] is not None:
+            # this shard holds pool pages [i * NP, (i + 1) * NP): the
+            # allocator keeps a slot's pages on the shard owning the slot
+            tab = tab - jax.lax.axis_index(shards[1]) * NP
+        kf = k_pages.transpose(0, 2, 1, 3)        # (NP, Hkv, ps, D)
+        vf = v_pages.transpose(0, 2, 1, 3)
+        return paged_flash_decode_pallas(q, kf, vf,
+                                         jnp.clip(tab, 0, NP - 1),
+                                         jnp.maximum(lengths, 1),
+                                         interpret=_interpret())
+
+    if shards is None:
+        return local(q, k_pages, v_pages, table, lengths)
+    mesh, s, h = shards
+    pool = P(s, None, h, None)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(s, h, None), pool, pool, P(s, None),
+                                   P(s)),
+                         out_specs=P(s, h, None), check_vma=False)(
+        q, k_pages, v_pages, table, lengths)
 
 
 @partial(jax.jit, static_argnames=("chunk",))

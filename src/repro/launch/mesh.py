@@ -7,6 +7,7 @@ the multi-pod config is 2 pods = 512 chips.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e hardware constants (per chip) — used by the roofline analysis.
 PEAK_FLOPS_BF16 = 197e12      # FLOP/s
@@ -14,16 +15,19 @@ HBM_BW = 819e9                # B/s
 ICI_BW = 50e9                 # B/s per link (approx, per direction)
 
 
+def _auto_mesh(shape, axes, devices=None):
+    """The one place a mesh is built.  Every axis is ``Auto``: the
+    installed JAX makes ``Explicit`` axes by default, under which the
+    embedding gather of a vocab-sharded table raises
+    ``ShardingTypeError``; the executors rely on GSPMD propagation."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(shape),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_local_mesh():
-    """Single-host mesh for smoke tests / examples (1 device)."""
-    n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _auto_mesh(shape, axes)
 
 
 def check_mp_divisibility(model_cfg, mp: int, *, spec: str = "") -> None:
@@ -42,13 +46,11 @@ def check_mp_divisibility(model_cfg, mp: int, *, spec: str = "") -> None:
         return
     from types import SimpleNamespace
 
-    import numpy as np
-
     from repro.models.transformer import decoder_param_schema
     from repro.sharding import model_axis_fallbacks
 
     stub = SimpleNamespace(axis_names=("data", "model"),
-                           devices=np.empty((1, mp), object))
+                           shape={"data": 1, "model": mp})
     _, fallbacks = model_axis_fallbacks(decoder_param_schema(model_cfg),
                                         stub)
     if fallbacks:
@@ -60,7 +62,7 @@ def check_mp_divisibility(model_cfg, mp: int, *, spec: str = "") -> None:
             "the model's head/FFN/vocab dims")
 
 
-def make_serving_mesh(spec: str, model_cfg=None):
+def make_serving_mesh(spec: str, model_cfg=None, *, devices=None):
     """Parse a ``dp=N[,mp=M]`` flag into a ``("data", "model")`` mesh.
 
     The serving executors shard the continuous engine's slot dimension
@@ -69,7 +71,7 @@ def make_serving_mesh(spec: str, model_cfg=None):
     parallel).  Pass the target ``model_cfg`` to validate up front that
     ``mp`` divides those dims (:func:`check_mp_divisibility`) instead
     of silently replicating params.  ``dp * mp`` must equal the
-    visible device count — use
+    number of ``devices`` (default: every visible device) — use
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` to test
     multi-device layouts on a CPU host.
     """
@@ -82,9 +84,10 @@ def make_serving_mesh(spec: str, model_cfg=None):
     mp = int(parts.get("mp", 1))
     if model_cfg is not None:
         check_mp_divisibility(model_cfg, mp, spec=spec)
-    n = len(jax.devices())
-    if dp * mp != n:
+    devices = list(jax.devices() if devices is None else devices)
+    if dp * mp != len(devices):
         raise ValueError(
-            f"mesh {spec!r} needs {dp * mp} devices but {n} are visible "
-            "(set XLA_FLAGS=--xla_force_host_platform_device_count)")
-    return jax.make_mesh((dp, mp), ("data", "model"))
+            f"mesh {spec!r} needs {dp * mp} devices but {len(devices)} "
+            "were given (set XLA_FLAGS="
+            "--xla_force_host_platform_device_count)")
+    return _auto_mesh((dp, mp), ("data", "model"), devices)

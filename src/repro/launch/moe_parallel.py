@@ -6,19 +6,6 @@ from functools import partial
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except (ImportError, TypeError):  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-
 from repro.core.config import ModelConfig
 from repro.models.moe import moe_apply_ep
 from repro.sharding import batch_axes
@@ -58,13 +45,14 @@ def make_ep_moe_fn(mesh: Mesh, capacity_factor: float = 1.25,
         prod = int(np.prod([sizes[a] for a in ba]))
         x_spec = P(ba if bdim % prod == 0 else None, None, None)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(_ep_body, cfg=cfg, capacity_factor=capacity_factor,
                     replica=replica, comm_dtype=comm_dtype,
                     scatter_down=scatter_down),
-            mesh,
+            mesh=mesh,
             in_specs=(param_specs, x_spec),
             out_specs=(x_spec, P()),
+            check_vma=False,
         )
         return fn(p, x)
 

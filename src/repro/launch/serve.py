@@ -34,6 +34,7 @@ import dataclasses
 from repro.core.config import TestbedConfig
 from repro.core.metrics import evaluate_actions
 from repro.core.offline_log import build_testbed
+from repro.launch.compile_cache import enable_compile_cache
 from repro.routing import (ConstrainedPolicy, Gateway, MLPPolicy, Request,
                            SimulatorBackend, get_action_space,
                            get_slo_profile, list_action_spaces,
@@ -41,29 +42,34 @@ from repro.routing import (ConstrainedPolicy, Gateway, MLPPolicy, Request,
 from repro.routing.registry import DEFAULT_SPACE
 
 
-def _continuous_backend(index, mesh_spec, num_slots, retrievers=None,
-                        cache_size: int = 0, clock=None):
-    """Real-model generation: ContinuousEngine over an optional mesh."""
+def continuous_backend(model_cfg, index, *, mesh_spec=None,
+                       num_slots: int = 8, max_prompt_len: int = 192,
+                       max_new_tokens: int = 8,
+                       retrievers=None, cache_size: int = 0, clock=None,
+                       **engine_kw):
+    """Real-model generation: ``model_cfg`` with random weights (seed
+    0) on a ContinuousEngine, over a ``dp=N[,mp=M]`` mesh when
+    ``mesh_spec`` is given.  ``engine_kw`` reaches the engine (e.g.
+    ``paged=True``, ``prefill_batch``)."""
     import jax
 
-    from repro.configs import get_config
     from repro.data.tokenizer import HashTokenizer
     from repro.launch.mesh import make_serving_mesh
     from repro.models import build_model
     from repro.routing import ContinuousEngineBackend
 
-    mcfg = get_config("qwen1.5-32b", "smoke")
-    model = build_model(mcfg)
+    model = build_model(model_cfg)
     params = model.init(jax.random.PRNGKey(0))
     # model_cfg: fail fast if mp doesn't divide the head/FFN dims
-    mesh = (make_serving_mesh(mesh_spec, model_cfg=mcfg)
+    mesh = (make_serving_mesh(mesh_spec, model_cfg=model_cfg)
             if mesh_spec else None)
-    kw = {} if clock is None else {"clock": clock}
+    if clock is not None:
+        engine_kw["clock"] = clock
     return ContinuousEngineBackend.create(
-        model, params, HashTokenizer(mcfg.vocab_size), index,
-        mesh=mesh, num_slots=num_slots, max_prompt_len=192,
-        max_new_tokens=8, retrievers=retrievers,
-        retrieval_cache_size=cache_size, **kw)
+        model, params, HashTokenizer(model_cfg.vocab_size), index,
+        mesh=mesh, num_slots=num_slots, max_prompt_len=max_prompt_len,
+        max_new_tokens=max_new_tokens, retrievers=retrievers,
+        retrieval_cache_size=cache_size, **engine_kw)
 
 
 def _dump_telemetry(args, tracer, metrics) -> None:
@@ -165,6 +171,7 @@ def main():
     args = ap.parse_args()
     if args.mesh and args.backend != "continuous":
         ap.error("--mesh requires --backend continuous")
+    enable_compile_cache()
 
     space = get_action_space(args.space)
     cfg = TestbedConfig()
@@ -210,10 +217,13 @@ def main():
         # backend wraps it behind its own cache when requested
         suite = (pipe.retrievers
                  if set(space.retriever_names) - {"bm25"} else None)
-        backend = _continuous_backend(index, args.mesh, args.num_slots,
-                                      retrievers=suite,
-                                      cache_size=args.retrieval_cache,
-                                      clock=clock.now if clock else None)
+        from repro.configs import get_config
+        backend = continuous_backend(get_config("qwen1.5-32b", "smoke"),
+                                     index, mesh_spec=args.mesh,
+                                     num_slots=args.num_slots,
+                                     retrievers=suite,
+                                     cache_size=args.retrieval_cache,
+                                     clock=clock.now if clock else None)
     else:
         if args.retrieval_cache and pipe.retrieval_cache is None:
             from repro.retrieval.hybrid import resolve_retrievers

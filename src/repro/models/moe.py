@@ -27,15 +27,6 @@ from repro.models.schema import ParamSpec
 from repro.models.layers import mlp_schema, mlp_apply
 
 
-def _axis_size(name: str) -> int:
-    """Mapped-axis size inside shard_map; ``jax.lax.axis_size`` only
-    exists on newer jax, so fall back to the classic psum(1) idiom
-    (concrete for a constant operand)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
 def moe_schema(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     d = cfg.d_model
     e: MoEConfig = cfg.moe
@@ -119,7 +110,7 @@ def moe_apply_ep(p, x, cfg: ModelConfig, *, data_axis: str = "data",
     """
     B, S, d = x.shape
     e = cfg.moe
-    n_shards = _axis_size(data_axis)
+    n_shards = jax.lax.axis_size(data_axis)
     E, E_loc = e.n_experts, e.n_experts // n_shards
     T = B * S
     xf = x.reshape(T, d)
@@ -161,7 +152,7 @@ def moe_apply_ep(p, x, cfg: ModelConfig, *, data_axis: str = "data",
     out = jnp.einsum("ecf,efd->ecd", h, wd)
     out = out.astype(send_dtype)
 
-    n_model = _axis_size(model_axis)
+    n_model = jax.lax.axis_size(model_axis)
     if scatter_down and d % n_model == 0:
         # §Perf it3: reduce-scatter the partial down-proj over the model
         # axis onto the d dim, send a d/n_model slice through the return

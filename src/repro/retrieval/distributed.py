@@ -25,8 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.launch.moe_parallel import shard_map
-
 
 def _merge_local_topk(scores, *, k: int, axis: str, d_local: int):
     """Local top-k -> globalized ids -> all-gather -> final top-k.
@@ -66,11 +64,12 @@ def distributed_topk(mesh: Mesh, score_fn: Callable, doc_arrays: Sequence,
         scores = score_fn(*docs_loc, q)
         return _merge_local_topk(scores, k=k, axis=axis, d_local=d_local)
 
-    fn = shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh,
         in_specs=tuple(P(axis, *([None] * (a.ndim - 1)))
                        for a in doc_arrays) + (P(None, None),),
         out_specs=(P(None, None), P(None, None)),
+        check_vma=False,
     )
     return jax.jit(fn)(*doc_arrays, qv)
 

@@ -67,6 +67,7 @@ numpy fake):
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Callable, Optional
 
@@ -187,6 +188,11 @@ class SingleDeviceExecutor:
                                donate_argnums=(1, 2, 3, 4, 6, 7))
         self._clear_flags = jax.jit(self._clear_flags_fn,
                                     donate_argnums=(0,))
+
+    def _mesh_context(self):
+        """Context the decode chunk is traced in (the sharded executor
+        sets its mesh, under which the decode kernels run per shard)."""
+        return contextlib.nullcontext()
 
     def _host_to_device(self, x: np.ndarray):
         return jnp.asarray(x)
@@ -417,14 +423,25 @@ class SingleDeviceExecutor:
         if self._m_admit is not None:
             self._m_admit.observe((time.perf_counter() - t0) * 1e3)
 
+    def _decode_args(self):
+        return (self.params, self._cache, self._dtok, self._dactive,
+                self._dgen, self._dlimit, self._dout, self._dbad)
+
     def decode_chunk(self) -> None:
         t0 = time.perf_counter() if self._m_decode is not None else 0.0
-        (self._cache, self._dtok, self._dactive, self._dgen,
-         self._dout, self._dbad) = self._decode(
-            self.params, self._cache, self._dtok, self._dactive,
-            self._dgen, self._dlimit, self._dout, self._dbad)
+        with self._mesh_context():
+            (self._cache, self._dtok, self._dactive, self._dgen,
+             self._dout, self._dbad) = self._decode(*self._decode_args())
         if self._m_decode is not None:
             self._m_decode.observe((time.perf_counter() - t0) * 1e3)
+
+    def compiled_decode_text(self) -> str:
+        """HLO text of the compiled decode-chunk program, to check which
+        kernels it runs (a Pallas kernel compiled for TPU shows up as a
+        ``tpu_custom_call``; an interpreted one does not)."""
+        with self._mesh_context():
+            return self._decode.lower(
+                *self._decode_args()).compile().as_text()
 
     def sync_control(self):
         """The every-K host sync: only the two tiny control arrays come
@@ -570,6 +587,9 @@ class ShardedExecutor(SingleDeviceExecutor):
             out_shardings=(self._cache_sh, s, s, s, self._out_sh, s))
         self._clear_flags = jax.jit(self._clear_flags_fn,
                                     donate_argnums=(0,), out_shardings=s)
+
+    def _mesh_context(self):
+        return jax.set_mesh(self.mesh)
 
     def _host_to_device(self, x: np.ndarray):
         # small host control inputs (slot ids, limits) ride replicated

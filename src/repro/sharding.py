@@ -34,14 +34,19 @@ FALLBACK_LOG: List[str] = []
 
 
 def mesh_axis_sizes(mesh: Mesh) -> Dict[str, int]:
-    return dict(zip(mesh.axis_names, mesh.devices.shape))
+    """Axis name -> size, for a ``Mesh`` or the ``AbstractMesh`` seen
+    while tracing under one."""
+    return dict(mesh.shape)
 
 
 def batch_axes(mesh: Mesh) -> Tuple[str, ...]:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
-def _batch_entry(dim: int, mesh: Mesh):
+def batch_entry(dim: int, mesh: Mesh):
+    """PartitionSpec entry for a ``batch`` dim of size ``dim`` (and the
+    mesh axes it uses): all batch axes when they divide it, else
+    ``data``, else replicated."""
     ba = batch_axes(mesh)
     sizes = mesh_axis_sizes(mesh)
     prod = int(np.prod([sizes[a] for a in ba]))
@@ -63,7 +68,7 @@ def resolve_spec(ps: ParamSpec, mesh: Mesh, *, fsdp: bool = True,
     # --- batch (activation / cache tensors) — first batch dim only
     for i, (ax, dim) in enumerate(zip(ps.axes, ps.shape)):
         if ax == "batch":
-            entry, u = _batch_entry(dim, mesh)
+            entry, u = batch_entry(dim, mesh)
             if not (u & used):
                 entries[i], used = entry, used | u
             break
@@ -164,7 +169,7 @@ def model_axis_fallbacks(schema, mesh: Mesh, *, fsdp: bool = False):
 
 def input_sharding(mesh: Mesh, batch: int, rank: int) -> NamedSharding:
     """Batch-sharded activation input: (B, ...) with B maybe indivisible."""
-    entry, _ = _batch_entry(batch, mesh)
+    entry, _ = batch_entry(batch, mesh)
     return NamedSharding(mesh, P(entry, *([None] * (rank - 1))))
 
 

@@ -38,61 +38,64 @@ def site_by_kernel(fname: str, kernel: str):
     raise AssertionError(f"no pallas_call with kernel {kernel} in {fname}")
 
 
-# -- flash_decode (dense): blocks (1,1,D) + 2x(1,1,block_kv,D|Dv) + (1,1);
-#    out (1,1,Dv); scratch (1,1)+(1,1)+(1,Dv) f32 ------------------------
+# -- flash_decode (dense): lengths ride scalar-prefetch (SMEM); blocks
+#    (1,1,G,D) + 2x(1,1,block_kv,D|Dv); out (1,1,G,Dv); scratch
+#    (G,1)+(G,1)+(G,Dv) f32.  G=5 is Qwen1.5-32B's 40/8 head grouping ----
 
 
 def test_flash_decode_hand_math():
     site = site_by_kernel("flash_decode.py", "_flash_decode_kernel")
-    b = {"D": 128, "Dv": 128, "block_kv": 128}
+    b = {"G": 5, "D": 128, "Dv": 128, "block_kv": 128}
     est = estimate_site(site, bindings=b)
-    in_elems = 128 + 128 * 128 + 128 * 128 + 1
-    assert est.in_bytes == in_elems * 4 == 131588
-    assert est.out_bytes == 128 * 4 == 512
-    assert est.scratch_bytes == (1 + 1 + 128) * 4 == 520
-    assert est.total_bytes == (131588 + 512) * 2 + 520 == 264720
+    in_elems = 5 * 128 + 128 * 128 + 128 * 128
+    assert est.in_bytes == in_elems * 4 == 133632
+    assert est.out_bytes == 5 * 128 * 4 == 2560
+    assert est.scratch_bytes == (5 + 5 + 5 * 128) * 4 == 2600
+    assert est.total_bytes == (133632 + 2560) * 2 + 2600 == 274984
 
 
 def test_flash_decode_int8_kv():
     site = site_by_kernel("flash_decode.py", "_flash_decode_kernel")
-    b = {"D": 128, "Dv": 128, "block_kv": 128}
+    b = {"G": 5, "D": 128, "Dv": 128, "block_kv": 128}
     est = estimate_site(site, bindings=b,
                         operand_dtypes={"k": "int8", "v": "int8"})
     # q stays f32 (out_shape dtype is q.dtype), k/v blocks drop to 1 B
-    assert est.in_bytes == 128 * 4 + 128 * 128 + 128 * 128 + 1 * 4
-    assert est.out_bytes == 512
-    assert est.total_bytes == (33284 + 512) * 2 + 520 == 68112
+    assert est.in_bytes == 5 * 128 * 4 + 128 * 128 + 128 * 128
+    assert est.out_bytes == 2560
+    assert est.total_bytes == (35328 + 2560) * 2 + 2600 == 78376
 
 
-# -- paged flash decode: PrefetchScalarGridSpec, table is scalar-prefetch --
+# -- paged flash decode: PrefetchScalarGridSpec, lengths + table are
+#    scalar-prefetch ----------------------------------------------------
 
 
 def test_paged_flash_decode_skips_scalar_prefetch_operand():
     site = site_by_kernel("flash_decode.py", "_paged_flash_decode_kernel")
-    assert site.num_scalar_prefetch == 1
-    assert site.operands[0] == "table"          # SMEM, not estimated
-    assert site.operands[1:] == ["q", "k_pages", "v_pages", "lens"]
+    assert site.num_scalar_prefetch == 2
+    assert site.operands[:2] == ["lens", "table"]   # SMEM, not estimated
+    assert site.operands[2:] == ["q", "k_pages", "v_pages"]
 
 
 @pytest.mark.parametrize("ps,expected_total", [
-    (16, (16900 + 512) * 2 + 520),     # in = (128+16*128*2+1)*4 = 16900
-    (32, (33284 + 512) * 2 + 520),     # in = (128+32*128*2+1)*4 = 33284
-    (64, (66052 + 512) * 2 + 520),     # in = (128+64*128*2+1)*4 = 66052
+    (16, (18944 + 2560) * 2 + 2600),   # in = (5*128+16*128*2)*4 = 18944
+    (32, (35328 + 2560) * 2 + 2600),   # in = (5*128+32*128*2)*4 = 35328
+    (64, (68096 + 2560) * 2 + 2600),   # in = (5*128+64*128*2)*4 = 68096
 ])
 def test_paged_flash_decode_page_size_sweep(ps, expected_total):
     site = site_by_kernel("flash_decode.py", "_paged_flash_decode_kernel")
-    est = estimate_site(site, bindings={"D": 128, "Dv": 128, "ps": ps})
+    est = estimate_site(site, bindings={"G": 5, "D": 128, "Dv": 128,
+                                        "ps": ps})
     assert est.total_bytes == expected_total
 
 
 def test_paged_flash_decode_int8_kv_pages():
     site = site_by_kernel("flash_decode.py", "_paged_flash_decode_kernel")
     est = estimate_site(
-        site, bindings={"D": 128, "Dv": 128, "ps": 64},
+        site, bindings={"G": 5, "D": 128, "Dv": 128, "ps": 64},
         operand_dtypes={"k_pages": "int8", "v_pages": "int8"})
-    in_bytes = 128 * 4 + 64 * 128 + 64 * 128 + 1 * 4
+    in_bytes = 5 * 128 * 4 + 64 * 128 + 64 * 128
     assert est.in_bytes == in_bytes
-    assert est.total_bytes == (in_bytes + 512) * 2 + 520
+    assert est.total_bytes == (in_bytes + 2560) * 2 + 2600
 
 
 # -- dense_topk: in (block_q,E)+(block_d,E); out 2x(block_q,k) f32/i32;

@@ -19,6 +19,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.data.tokenizer import trim_at_eos as _trim
+from repro.launch.mesh import make_serving_mesh
 from repro.models import build_model
 from repro.serving.continuous import ContinuousEngine
 
@@ -43,7 +44,7 @@ def test_sharded_1device_mesh_token_parity(qwen):
                               max_new_cap=16, sync_every=4,
                               prefill_batch=3)
     a = single.generate_many(prompts, max_new_tokens=12)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_serving_mesh("dp=1")
     sharded = ContinuousEngine(model, params, num_slots=3, max_len=64,
                                max_new_cap=16, sync_every=4,
                                prefill_batch=3, mesh=mesh)
@@ -62,6 +63,7 @@ import jax, numpy as np
 
 from repro.configs import get_config
 from repro.data.tokenizer import trim_at_eos as trim
+from repro.launch.mesh import make_serving_mesh
 from repro.models import build_model
 from repro.serving.continuous import ContinuousEngine
 
@@ -77,7 +79,7 @@ single = ContinuousEngine(model, params, num_slots=8, max_len=64,
                           max_new_cap=16, sync_every=4, prefill_batch=4)
 a = single.generate_many(prompts, max_new_tokens=12)
 
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = make_serving_mesh("dp=8")
 sharded = ContinuousEngine(model, params, num_slots=8, max_len=64,
                            max_new_cap=16, sync_every=4, prefill_batch=4,
                            mesh=mesh)

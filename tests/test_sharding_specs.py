@@ -12,7 +12,6 @@ devices needed.  They pin the dp×mp serving contract:
 import dataclasses
 from types import SimpleNamespace
 
-import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
@@ -25,9 +24,9 @@ from repro.sharding import (leaf_name, model_axis_fallbacks, resolve_spec,
 
 def stub_mesh(dp: int, mp: int):
     """Duck-typed mesh: resolve_spec only touches axis_names and
-    devices.shape."""
+    shape."""
     return SimpleNamespace(axis_names=("data", "model"),
-                           devices=np.empty((dp, mp), object))
+                           shape={"data": dp, "model": mp})
 
 
 @pytest.fixture(scope="module")
