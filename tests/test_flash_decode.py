@@ -1,6 +1,7 @@
 """Flash-decode kernel (interpret mode) vs the dense jnp oracle, and
 the model decode path wired through it."""
 import dataclasses
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -213,7 +214,7 @@ def test_flash_decode_sharded_dp2_mp2_token_parity():
     root = Path(__file__).resolve().parents[1]
     out = subprocess.run(
         [sys.executable, "-c", SCRIPT_SHARDED],
-        env={"PYTHONPATH": str(root / "src"), "PATH": "/usr/bin:/bin",
-             "HOME": "/tmp", "JAX_PLATFORMS": "cpu"},
+        env=dict(os.environ, PYTHONPATH=str(root / "src"),
+                 JAX_PLATFORMS="cpu"),
         capture_output=True, text=True, timeout=900)
     assert "FLASH-SHARDED-PARITY-OK" in out.stdout, out.stderr[-2000:]
