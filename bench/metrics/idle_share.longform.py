@@ -1,0 +1,7 @@
+"""Share of the traced slice in which no operation ran on the most
+idle chip."""
+import readers
+
+
+def read(ctx):
+    return readers.idle_share(ctx)
