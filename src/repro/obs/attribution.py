@@ -3,9 +3,16 @@
 The serving stack marks every request with a contiguous top-level stage
 chain — ``queue_wait → admission → prefill → decode → harvest`` — whose
 durations sum to the end-to-end latency *by construction* (each stage
-ends where the next begins).  ``retrieval`` is a child interval inside
-``admission`` (the gateway performs retrieval while preparing the
-submit), so it attributes without double-counting.
+ends where the next begins).  ``prefill`` runs from the dispatch into
+the engine to the request's first token, as the first control sync
+that shows it reports it.  Nested stages lie inside one top-level
+stage and attribute without double-counting (``NESTED``):
+
+* ``lock_wait`` in ``queue_wait``: from arrival until the submitting
+  thread holds the gateway lock and has enqueued the request;
+* ``route`` in ``admission``: routing the batch the request was in;
+* ``retrieval`` and ``tokenize`` in ``admission``: the backend's
+  lookup and prompt encoding while it submits the request.
 
 ``SLOBudgetTracker`` consumes ``RequestBreakdown`` rows so a burn-rate
 report can name the dominant stage: "p99 is burning and 70% of it is
@@ -20,8 +27,24 @@ from typing import Deque, Dict, Tuple
 # Top-level stages are contiguous and sum to end-to-end latency.
 TOP_LEVEL: Tuple[str, ...] = (
     "queue_wait", "admission", "prefill", "decode", "harvest")
-# All stage names a breakdown may carry (retrieval nests in admission).
-STAGES: Tuple[str, ...] = TOP_LEVEL + ("retrieval",)
+# Nested stage -> the top-level stage it lies inside.
+NESTED: Dict[str, str] = {"lock_wait": "queue_wait", "route": "admission",
+                          "retrieval": "admission", "tokenize": "admission"}
+# All stage names a breakdown may carry.
+STAGES: Tuple[str, ...] = TOP_LEVEL + tuple(NESTED)
+
+
+def _net_weights(stages: Dict[str, float]) -> Dict[str, float]:
+    """Top-level stages net of the nested stages inside them, plus the
+    nested stages themselves: disjoint intervals that compete on their
+    own merits."""
+    weights = {s: stages.get(s, 0.0) for s in TOP_LEVEL}
+    for s, up in NESTED.items():
+        v = stages.get(s, 0.0)
+        if v > 0.0:
+            weights[up] = max(0.0, weights[up] - v)
+            weights[s] = v
+    return weights
 
 # Terminal kinds a breakdown can describe.
 KINDS: Tuple[str, ...] = ("completed", "shed", "timed_out", "faulted")
@@ -46,15 +69,10 @@ class RequestBreakdown:
 
     @property
     def dominant_stage(self) -> str:
-        """Largest attributed interval.  retrieval competes directly:
-        its parent (admission) is reduced by the nested retrieval time
-        so one of them wins on its own merits."""
-        weights = {s: self.stages.get(s, 0.0) for s in TOP_LEVEL}
-        retr = self.stages.get("retrieval", 0.0)
-        if retr > 0.0:
-            weights["admission"] = max(
-                0.0, weights.get("admission", 0.0) - retr)
-            weights["retrieval"] = retr
+        """Largest attributed interval.  Nested stages compete directly:
+        each parent is reduced by the nested time inside it so one of
+        them wins on its own merits."""
+        weights = _net_weights(self.stages)
         if not any(weights.values()):
             return "queue_wait"
         return max(weights, key=lambda s: (weights[s], s))
@@ -85,7 +103,8 @@ class StageAttribution:
 
     def report(self) -> Dict[str, object]:
         """Mean per-stage ms + share of total attributed time, plus the
-        stage that dominates the window (admission net of retrieval)."""
+        stage that dominates the window (each parent net of its nested
+        stages)."""
         if not self._rows:
             return {"n": 0, "dominant_stage": None,
                     "stage_ms": {}, "stage_share": {}}
@@ -94,10 +113,7 @@ class StageAttribution:
             for s in STAGES:
                 sums[s] += bd.stages.get(s, 0.0)
         n = len(self._rows)
-        retr = sums["retrieval"]
-        weights = {s: sums[s] for s in TOP_LEVEL}
-        weights["admission"] = max(0.0, weights["admission"] - retr)
-        weights["retrieval"] = retr
+        weights = _net_weights(sums)
         total = sum(weights.values()) or 1.0
         dominant = max(weights, key=lambda s: (weights[s], s))
         return {

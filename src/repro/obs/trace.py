@@ -21,24 +21,50 @@ keyed by *question* id while the gateway tracks *request* qids.  The
 backend notes an anonymous span; the gateway — single-threaded under
 the pump lock — adopts pending notes onto the qid it just submitted.
 
+Host spans are the other half: ``with tracer.span(name, **attrs)``
+around a piece of host work (the gateway's pump iteration, routing,
+one submit, one engine step and its dispatches and syncs).  A span
+records its interval, its own id, the id of the innermost span open on
+the same thread (its parent) and the thread, into one bounded deque,
+and while it is open it holds a profiler annotation of the same name
+(``jax.profiler.TraceAnnotation``, imported when a tracer is built, so
+this module needs nothing beyond the standard library): a profiler
+capture then shows the program's spans on the host line of the thread
+that ran them, in the same trace as the device's operations.
+
 ``NULL_TRACER`` is the disabled path: every method is a constant-return
-no-op (no clock reads, no allocation), so instrumented code never
-branches on "is tracing on" and the healthy-path parity test can assert
-token-identical outputs either way.
+no-op (no clock reads, no allocation; ``span`` hands back one shared
+inert context manager and opens no annotation), so instrumented code
+never branches on "is tracing on" and the healthy-path parity test can
+assert token-identical outputs either way.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import math
 import random
+import threading
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
-from repro.obs.attribution import (KINDS, STAGES, TOP_LEVEL,
+from repro.obs.attribution import (KINDS, NESTED, STAGES, TOP_LEVEL,
                                    RequestBreakdown, StageAttribution)
 
 _EPS_S = 1e-9
+# host spans kept per tracer (oldest dropped first)
+MAX_SPANS = 4096
+_DEFAULT = object()
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None without JAX."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 @dataclass(slots=True)
@@ -49,6 +75,85 @@ class Span:
     t0: float
     t1: float
     attrs: Dict[str, object] = field(default_factory=dict)
+
+
+class HostSpan:
+    """One host span: a context manager while open, then the record
+    the tracer keeps.  ``sid`` is its id (from 1), ``parent`` the id
+    of the span that was innermost on the same thread when it opened
+    (0: none), ``thread`` that thread's ident."""
+
+    __slots__ = ("name", "t0", "t1", "sid", "parent", "thread", "attrs",
+                 "n_children", "_tracer", "_ann", "_keep")
+
+    def __init__(self, tracer: "Tracer", name: str,
+                 attrs: Dict[str, object]) -> None:
+        self.name = name
+        self.attrs = attrs
+        self.t0 = self.t1 = 0.0
+        self.sid = self.parent = self.thread = 0
+        self.n_children = 0
+        self._tracer = tracer
+        self._ann = None
+        self._keep = True
+
+    def set(self, **attrs: object) -> None:
+        self.attrs.update(attrs)
+
+    def drop(self) -> None:
+        """Keep no record of this span (it still closes normally)."""
+        self._keep = False
+
+    def __enter__(self) -> "HostSpan":
+        tr = self._tracer
+        stack = tr._stack()
+        self.parent = stack[-1].sid if stack else 0
+        self.sid = next(tr._ids)
+        self.thread = threading.get_ident()
+        stack.append(self)
+        if tr._annotate is not None:
+            self._ann = tr._annotate(self.name)
+            self._ann.__enter__()
+        self.t0 = tr.clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        tr = self._tracer
+        self.t1 = tr.clock()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        stack = tr._stack()
+        stack.pop()
+        if self._keep:
+            if stack:
+                stack[-1].n_children += 1
+            tr.spans.append(self)
+
+
+class _NullSpan:
+    """The disabled tracer's one shared span: enters and exits at no
+    cost and keeps nothing."""
+
+    __slots__ = ()
+    name = ""
+    t0 = t1 = 0.0
+    sid = parent = thread = n_children = 0
+
+    def set(self, **attrs: object) -> None:
+        pass
+
+    def drop(self) -> None:
+        pass
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NULL_SPAN = _NullSpan()
 
 
 @dataclass(slots=True)
@@ -97,18 +202,28 @@ class Tracer:
 
     def __init__(self, clock: Callable[[], float], *,
                  max_trees: int = 512, max_breakdowns: int = 4096,
-                 stage_reservoir: int = 4096, seed: int = 0) -> None:
+                 stage_reservoir: int = 4096, seed: int = 0,
+                 annotate=_DEFAULT) -> None:
+        """``annotate(name)`` returns the context manager a span holds
+        while open; the default is ``jax.profiler.TraceAnnotation``
+        (none without JAX), ``None`` opens none."""
         if not callable(clock):
             raise TypeError("Tracer requires an injectable clock "
                             "callable as its first argument")
         self.clock = clock
+        self._annotate = (_profiler_annotation() if annotate is _DEFAULT
+                          else annotate)
+        self._ids = itertools.count(1)
+        self._local = threading.local()
+        self._thread_names: Dict[int, str] = {}
+        # host spans, in the order they closed (children before parents)
+        self.spans: Deque[HostSpan] = deque(maxlen=MAX_SPANS)
         self.max_trees = max_trees
         self._rng = random.Random(seed)
         self._active: Dict[int, RequestTree] = {}
         self._trees: List[RequestTree] = []
         self._n_finished = 0           # drives algorithm-R tree sampling
         self._pending: List[Span] = []
-        self.engine_spans: Deque[Span] = deque(maxlen=4096)
         self.breakdowns: Deque[RequestBreakdown] = deque(
             maxlen=max_breakdowns)
         self._stage_res: Dict[str, _Reservoir] = {
@@ -118,6 +233,21 @@ class Tracer:
     # -- hot-path API ----------------------------------------------------
     def now(self) -> float:
         return self.clock()
+
+    def span(self, name: str, **attrs: object) -> HostSpan:
+        """``with tracer.span("engine.step"):`` records a host span
+        (see :class:`HostSpan`); ``as sp`` gives it for ``sp.set``."""
+        return HostSpan(self, name, attrs)
+
+    def _stack(self) -> List[HostSpan]:
+        """This thread's open spans, innermost last."""
+        try:
+            return self._local.stack
+        except AttributeError:
+            self._local.stack = []
+            t = threading.current_thread()
+            self._thread_names[t.ident] = t.name
+            return self._local.stack
 
     def begin_request(self, qid: int, t: float) -> None:
         """Open the root span (idempotent: a retry re-begin is a no-op)."""
@@ -159,12 +289,6 @@ class Tracer:
         """Drop noted spans that cannot be attributed (batched closed-
         loop execution interleaves notes across requests)."""
         self._pending = []
-
-    def engine_span(self, name: str, t0: float, t1: float,
-                    **attrs: object) -> None:
-        """Engine-level span not tied to one request (prefill dispatch,
-        decode chunk).  Bounded deque; rendered on its own track."""
-        self.engine_spans.append(Span(name, t0, t1, attrs))
 
     def finish_request(self, qid: int, kind: str,
                        t: Optional[float] = None,
@@ -232,15 +356,24 @@ class Tracer:
 
     def chrome_trace(self) -> Dict[str, object]:
         """Chrome trace-event JSON (open in Perfetto / chrome://tracing).
-        Request trees render as pid 1 with one tid per qid; engine spans
-        share pid 0 / tid 0.  ts/dur are microseconds of the injected
-        clock domain."""
+        Request trees render as pid 1 with one tid per qid; host spans
+        as pid 0 with one tid per thread, their ``sid`` and ``parent``
+        in their args.  ts/dur are microseconds of the injected clock
+        domain."""
         events: List[Dict[str, object]] = [
             {"name": "process_name", "ph": "M", "pid": 0, "tid": 0,
-             "args": {"name": "engine"}},
+             "args": {"name": "host"}},
             {"name": "process_name", "ph": "M", "pid": 1, "tid": 0,
              "args": {"name": "requests"}},
         ]
+        tids: Dict[int, int] = {}
+        for sp in self.spans:
+            if sp.thread not in tids:
+                tids[sp.thread] = len(tids)
+                events.append({"name": "thread_name", "ph": "M", "pid": 0,
+                               "tid": tids[sp.thread], "args": {
+                                   "name": self._thread_names.get(
+                                       sp.thread, str(sp.thread))}})
 
         def ev(name, t0, t1, pid, tid, args):
             return {"name": name, "ph": "X", "cat": "repro",
@@ -248,8 +381,10 @@ class Tracer:
                     "dur": round(max(0.0, t1 - t0) * 1e6, 3),
                     "pid": pid, "tid": tid, "args": args}
 
-        for sp in self.engine_spans:
-            events.append(ev(sp.name, sp.t0, sp.t1, 0, 0, sp.attrs))
+        for sp in self.spans:
+            events.append(ev(sp.name, sp.t0, sp.t1, 0, tids[sp.thread],
+                             {"sid": sp.sid, "parent": sp.parent,
+                              **sp.attrs}))
         for tree in self._trees:
             end = tree.end if tree.end is not None else tree.start
             events.append(ev(f"request[{tree.kind}]", tree.start, end,
@@ -273,7 +408,9 @@ class Tracer:
 
     def problems(self) -> List[str]:
         """Well-formedness audit: every sampled span closed and inside
-        its root interval; no requests left open.  Empty list == clean
+        its root interval, every nested stage inside its parent stage,
+        every host span inside its parent (when the parent is still in
+        the buffer); no requests left open.  Empty list == clean
         (asserted by the CI obs-smoke job)."""
         out: List[str] = []
         for qid in sorted(self._active):
@@ -290,9 +427,22 @@ class Tracer:
                         or sp.t1 > tree.end + _EPS_S):
                     out.append(f"request {tree.qid} span {sp.name} "
                                f"escapes root interval")
-        for sp in self.engine_spans:
+            by_name = {sp.name: sp for sp in tree.spans}
+            for sp in tree.spans:
+                up = by_name.get(NESTED.get(sp.name, ""))
+                if up is not None and (sp.t0 < up.t0 - _EPS_S
+                                       or sp.t1 > up.t1 + _EPS_S):
+                    out.append(f"request {tree.qid} stage {sp.name} "
+                               f"escapes {up.name}")
+        by_sid = {sp.sid: sp for sp in self.spans}
+        for sp in self.spans:
             if sp.t1 < sp.t0 - _EPS_S:
-                out.append(f"engine span {sp.name} ends before it starts")
+                out.append(f"host span {sp.name} ends before it starts")
+            up = by_sid.get(sp.parent)
+            if up is not None and (sp.t0 < up.t0 - _EPS_S
+                                   or sp.t1 > up.t1 + _EPS_S):
+                out.append(f"host span {sp.name} ({sp.sid}) escapes its "
+                           f"parent {up.name} ({up.sid})")
         return out
 
 
@@ -302,11 +452,14 @@ class NullTracer:
     on enablement."""
 
     enabled = False
-    engine_spans: Tuple[()] = ()
+    spans: Tuple[()] = ()
     breakdowns: Tuple[()] = ()
 
     def now(self) -> float:
         return 0.0
+
+    def span(self, name, **attrs) -> _NullSpan:
+        return _NULL_SPAN
 
     def begin_request(self, qid, t) -> None:
         pass
@@ -321,9 +474,6 @@ class NullTracer:
         pass
 
     def discard_pending(self) -> None:
-        pass
-
-    def engine_span(self, name, t0, t1, **attrs) -> None:
         pass
 
     def finish_request(self, qid, kind, t=None, cost_tokens=0.0) -> None:

@@ -39,6 +39,7 @@ from typing import (Dict, List, Mapping, Optional, Protocol, Sequence,
 import numpy as np
 
 from repro.core.errors import CircuitOpenError, TransientFaultError
+from repro.obs import NULL_TRACER
 
 
 @runtime_checkable
@@ -389,20 +390,27 @@ def retrieve_with_fallback(retrievers: Mapping[str, Retriever],
     retry path.
 
     ``tracer`` (a :class:`repro.obs.Tracer`, or None/``NULL_TRACER``)
-    records the lookup as an anonymous ``retrieval`` span — this layer
-    doesn't know the request qid, so the gateway adopts the note onto
-    the request it is submitting (see ``Tracer.note``/``adopt``).
+    records the lookup as a ``backend.retrieval`` host span and as an
+    anonymous ``retrieval`` stage — this layer doesn't know the request
+    qid, so the gateway adopts the note onto the request it is
+    submitting (see ``Tracer.note``/``adopt``).
     """
+    tr = tracer if tracer is not None else NULL_TRACER
+    with tr.span("backend.retrieval", retriever=name, k=k):
+        return _retrieve_with_fallback(retrievers, name, query, k,
+                                       fallback, tr)
+
+
+def _retrieve_with_fallback(retrievers, name, query, k, fallback, tracer):
     primary = retrievers[name]
-    t0 = tracer.now() if tracer is not None else 0.0
+    t0 = tracer.now()
     try:
         passages = primary.passages(query, k)
     except Exception as exc:
         fb = retrievers.get(fallback)
         if fb is None or name == fallback:
-            if tracer is not None:
-                tracer.note("retrieval", t0, tracer.now(),
-                            retriever=name, k=k, failed=True)
+            tracer.note("retrieval", t0, tracer.now(),
+                        retriever=name, k=k, failed=True)
             if isinstance(exc, TransientFaultError):
                 raise
             raise TransientFaultError(
@@ -410,19 +418,16 @@ def retrieve_with_fallback(retrievers: Mapping[str, Retriever],
         try:
             out = fb.passages(query, k), True
         except Exception as fb_exc:
-            if tracer is not None:
-                tracer.note("retrieval", t0, tracer.now(),
-                            retriever=name, k=k, failed=True)
+            tracer.note("retrieval", t0, tracer.now(),
+                        retriever=name, k=k, failed=True)
             raise TransientFaultError(
                 f"retriever {name!r} and fallback {fallback!r} both "
                 f"failed: {exc}; {fb_exc}") from fb_exc
-        if tracer is not None:
-            tracer.note("retrieval", t0, tracer.now(),
-                        retriever=name, k=k, degraded=True,
-                        fallback=fallback)
+        tracer.note("retrieval", t0, tracer.now(),
+                    retriever=name, k=k, degraded=True,
+                    fallback=fallback)
         return out
-    if tracer is not None:
-        tracer.note("retrieval", t0, tracer.now(), retriever=name, k=k)
+    tracer.note("retrieval", t0, tracer.now(), retriever=name, k=k)
     return passages, False
 
 

@@ -50,13 +50,16 @@ from repro.serving.pipeline import ActionOutcome, RAGPipeline
 @dataclass(frozen=True)
 class StreamCompletion:
     """One finished in-flight request: its outcome plus the backend
-    clock stamps open-loop latency accounting needs (``admitted_at`` is
-    when the first token was produced — prefill dispatch)."""
+    clock stamps open-loop latency accounting needs: ``admitted_at``
+    when its prefill was dispatched, ``first_token_at`` when the
+    backend first saw its first token (0.0: it produced none),
+    ``finished_at`` when it finished."""
 
     rid: int
     outcome: ActionOutcome
     admitted_at: float
     finished_at: float
+    first_token_at: float
 
 
 @runtime_checkable
@@ -81,7 +84,8 @@ class SimulatorBackend:
     Streaming runs the pipeline's (instant) outcome through a synthetic
     service model: at most ``stream_slots`` requests in service, each
     occupying its slot for ``service_polls`` ``stream_poll`` calls,
-    FIFO admission from a waiting queue.  Entirely deterministic.
+    FIFO admission from a waiting queue; its first token is seen at
+    the first poll after it entered service.  Entirely deterministic.
     """
 
     def __init__(self, pipeline: RAGPipeline, *, stream_slots: int = 4,
@@ -92,7 +96,7 @@ class SimulatorBackend:
         self._clock = clock if clock is not None else time.perf_counter
         self._next_rid = 0
         # waiting: (rid, outcome); in service: [rid, outcome, polls_left,
-        # admitted_at]
+        # admitted_at, first_token_at]
         self._waiting: Deque[Tuple[int, ActionOutcome]] = deque()
         self._in_service: List[list] = []
 
@@ -143,7 +147,8 @@ class SimulatorBackend:
         now = self._clock()
         while self._waiting and len(self._in_service) < self.stream_slots:
             rid, out = self._waiting.popleft()
-            self._in_service.append([rid, out, self.service_polls, now])
+            self._in_service.append([rid, out, self.service_polls, now,
+                                     0.0])
 
     def stream_poll(self) -> List[StreamCompletion]:
         self._fill_slots()
@@ -152,10 +157,13 @@ class SimulatorBackend:
         now = self._clock()
         for entry in self._in_service:
             entry[2] -= 1
+            if entry[2] == self.service_polls - 1:
+                entry[4] = now
             if entry[2] <= 0:
                 done.append(StreamCompletion(
                     rid=entry[0], outcome=entry[1],
-                    admitted_at=entry[3], finished_at=now))
+                    admitted_at=entry[3], finished_at=now,
+                    first_token_at=entry[4]))
             else:
                 keep.append(entry)
         self._in_service = keep

@@ -26,7 +26,7 @@ from __future__ import annotations
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 from repro.data.synthetic_squad import Question
-from repro.data.tokenizer import HashTokenizer
+from repro.data.tokenizer import PAD, HashTokenizer
 from repro.generation.prompts import REFUSAL_TEXT, build_prompt
 from repro.obs import NULL_TRACER
 from repro.retrieval.bm25 import BM25Index
@@ -45,8 +45,8 @@ REFUSE_COST_TOKENS = 5.0
 class EngineBackend:
     """Batched retrieval + real JAX generation for one action bucket."""
 
-    # telemetry: the Gateway installs its tracer here so retrieval and
-    # engine spans land in the same trace (no-op by default)
+    # telemetry: the Gateway installs its tracer here so retrieval,
+    # tokenize and engine spans land in the same trace (no-op default)
     tracer = NULL_TRACER
 
     def __init__(self, engine: Engine, tokenizer: HashTokenizer,
@@ -120,9 +120,15 @@ class EngineBackend:
                 tracer=self.tracer)
         hit = bool(q.gold_answer) and any(
             q.gold_answer in p for p in passages)
-        prompt = build_prompt(action.mode, q.text, passages)
-        return self.tok.encode(prompt, bos=True,
-                               max_len=self.max_prompt_len), hit, degraded
+        tr = self.tracer
+        with tr.span("backend.tokenize") as sp:
+            prompt = build_prompt(action.mode, q.text, passages)
+            toks = self.tok.encode(prompt, bos=True,
+                                   max_len=self.max_prompt_len)
+        if tr.enabled:
+            sp.set(padded=len(toks), unpadded=len(toks) - toks.count(PAD))
+            tr.note("tokenize", sp.t0, sp.t1)
+        return toks, hit, degraded
 
     @staticmethod
     def _refusal_outcome(q: Question, action: Action) -> ActionOutcome:
@@ -362,5 +368,6 @@ class ContinuousEngineBackend(EngineBackend):
                                               gen.n_steps, hit, degraded)
             done.append(StreamCompletion(
                 rid=rid, outcome=out, admitted_at=gen.admitted_at,
-                finished_at=gen.finished_at))
+                finished_at=gen.finished_at,
+                first_token_at=gen.first_token_at))
         return done

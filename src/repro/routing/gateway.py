@@ -165,7 +165,7 @@ class Gateway:
         self.stats = GatewayStats()
         self.queue: List[Request] = []
         # hand the tracer to layers below the gateway (backend retrieval
-        # spans, engine prefill/decode-chunk spans)
+        # and tokenize, the engine's step spans)
         install = getattr(self.backend, "install_tracer", None)
         if install is not None and self.tracer.enabled:
             install(self.tracer)
@@ -211,13 +211,16 @@ class Gateway:
         self.queue.extend(reqs)
 
     def _route(self, batch: List[Request]):
-        states = self.state_fn([r.question for r in batch])
+        tr = self.tracer
+        with tr.span("gateway.route.features", n=len(batch)):
+            states = self.state_fn([r.question for r in batch])
         cap = None
         if self.adaptive:
             cap = self.budget.refusal_cap_adjustment(self.base_share)
         ctx = RoutingContext(refusal_cap=cap, action_space=self.space)
         slos = [r.slo for r in batch]
-        return self.policy.route(states, slos, ctx), cap
+        with tr.span("gateway.route.policy", n=len(batch)):
+            return self.policy.route(states, slos, ctx), cap
 
     def _account(self, r: Request, a: int, out, lat_ms: float) -> None:
         """Reward + error-budget bookkeeping for one served request."""
@@ -323,7 +326,8 @@ class Gateway:
             self.queue[self.max_batch:]
         tr = self.tracer
         t_pop = tr.now()
-        decision, cap = self._route(batch)
+        with tr.span("gateway.route", n=len(batch)):
+            decision, cap = self._route(batch)
         # only log the cap when the policy actually enforced it — a
         # logit-less policy (e.g. FixedPolicy) cannot demote refusals,
         # and the history must not claim back-pressure that was a no-op

@@ -75,10 +75,12 @@ class CompletedGeneration:
     n_steps: int              # == len(tokens)
     prompt_len: int
     finished_at: float = 0.0  # engine clock at harvest (latency)
-    # engine clock when the prefill was dispatched — the prefill emits
-    # the request's first token, so this is the time-to-first-token
-    # stamp open-loop serving reports against per-request deadlines
+    # engine clock when the prefill was dispatched
     admitted_at: float = 0.0
+    # engine clock when the first control sync that showed the
+    # request's first token returned (0.0: it produced none) — the
+    # time-to-first-token stamp open-loop serving reports
+    first_token_at: float = 0.0
     failed: str = ""          # non-empty: not served (reason)
     # failed on a retryable fault (quarantined slot, executor fault) —
     # the gateway may resubmit within the request's deadline
@@ -129,8 +131,8 @@ class ContinuousEngine:
     """
 
     # telemetry: the Gateway's tracer lands here via the backend's
-    # install_tracer (engine decode-chunk / prefill-dispatch spans);
-    # the default is the zero-overhead no-op
+    # install_tracer (engine.step and its harvest, dispatch and sync
+    # spans); the default is the zero-overhead no-op
     tracer = NULL_TRACER
 
     def __init__(self, model=None, params=None, *, num_slots: int = 8,
@@ -221,6 +223,10 @@ class ContinuousEngine:
         self._queue: Deque[SlotRequest] = deque()
         self._results: Dict[int, CompletedGeneration] = {}
         self._admitted_at: Dict[int, float] = {}
+        # slots admitted whose first token no sync has shown yet, and
+        # the first-token stamps of resident requests (rid -> time)
+        self._await_token = np.zeros(S, bool)
+        self._first_token_at: Dict[int, float] = {}
         self._auto_rid = 0
         self._bound_registries: Set[int] = set()
         if metrics is not None:
@@ -434,12 +440,19 @@ class ContinuousEngine:
                         self._queue.appendleft(req)
                     self.stats.n_deferred_admissions += 1
                     break
-            t_adm0 = self.tracer.now()
+            tr = self.tracer
             try:
-                if plans is not None:
-                    self._dispatch_paged(toks, slot_idx, limits, plans)
-                else:
-                    self.executor.admit(toks, slot_idx, limits)
+                with tr.span("engine.prefill_dispatch") as sp:
+                    if tr.enabled:
+                        p0 = plans[0].p0 if plans is not None else 0
+                        sp.set(rids=[req.rid for req in group],
+                               padded=PB * (plen - p0),
+                               unpadded=int(np.count_nonzero(
+                                   toks[:, p0:] != PAD)))
+                    if plans is not None:
+                        self._dispatch_paged(toks, slot_idx, limits, plans)
+                    else:
+                        self.executor.admit(toks, slot_idx, limits)
             except TransientFaultError as exc:
                 self.stats.n_exec_faults += 1
                 if plans is not None:
@@ -463,9 +476,6 @@ class ContinuousEngine:
                 self.stats.n_cow_forks = self._pages.n_cow_forks
                 self.stats.n_pages_evicted = self._pages.n_evicted
             self.stats.n_prefills += 1
-            self.tracer.engine_span("prefill_dispatch", t_adm0,
-                                    self.tracer.now(), n=len(group),
-                                    plen=int(plen))
             now = self._clock()
             for req, slot in zip(group, slots):
                 self.stats.n_admitted += 1
@@ -473,6 +483,7 @@ class ContinuousEngine:
                 self._slot_req[slot] = req
                 self._plen[slot] = plen
                 self._admitted_at[req.rid] = now
+                self._await_token[slot] = True
                 self._dirty.add(slot)
                 self._stall[slot] = 0
                 self._last_gen[slot] = -1
@@ -484,8 +495,20 @@ class ContinuousEngine:
     # -- sync + harvest ------------------------------------------------
 
     def _sync(self) -> None:
-        self._active, self._gen = self.executor.sync_control()
+        """Block on the control arrays (the span ends when the device
+        work dispatched before it is done), then stamp the first token
+        of every slot this sync shows one for."""
+        with self.tracer.span("engine.sync_wait"):
+            self._active, self._gen = self.executor.sync_control()
         self._dirty.clear()
+        seen = self._await_token & (self._gen >= 1)
+        if seen.any():
+            now = self._clock()
+            for s in np.flatnonzero(seen):
+                rid = self._rid[s]
+                if rid is not None:
+                    self._first_token_at[rid] = now
+            self._await_token &= ~seen
 
     def _harvest(self) -> None:
         done_slots = [s for s in range(self.num_slots)
@@ -494,7 +517,8 @@ class ContinuousEngine:
         if not done_slots:
             return
         # fetch the output buffer only when something actually finished
-        out = self.executor.fetch_outputs()
+        with self.tracer.span("engine.harvest", n=len(done_slots)):
+            out = self.executor.fetch_outputs()
         now = self._clock()
         for slot in done_slots:
             n = int(self._gen[slot])
@@ -503,7 +527,8 @@ class ContinuousEngine:
                 rid=rid, tokens=out[slot, :n].copy(),
                 n_steps=n, prompt_len=int(self._plen[slot]),
                 finished_at=now,
-                admitted_at=self._admitted_at.pop(rid, now))
+                admitted_at=self._admitted_at.pop(rid, now),
+                first_token_at=self._first_token_at.pop(rid, now))
             self.stats.n_completed += 1
             self._requeues.pop(rid, None)
             self._rid[slot] = None
@@ -532,6 +557,7 @@ class ContinuousEngine:
         head (up to ``max_requeues`` times) or complete it failed with
         ``transient=True`` so the gateway's retry path can take over."""
         self._admitted_at.pop(req.rid, None)
+        self._first_token_at.pop(req.rid, None)
         if self._requeues.get(req.rid, 0) < self.max_requeues:
             self._requeues[req.rid] = self._requeues.get(req.rid, 0) + 1
             self.stats.n_requeued += 1
@@ -612,7 +638,8 @@ class ContinuousEngine:
         for s in expired:
             req = self._slot_req[s]
             self._time_out(req, admitted_at=self._admitted_at.pop(
-                req.rid, now))
+                req.rid, now), first_token_at=self._first_token_at.pop(
+                    req.rid, 0.0))
             self._active[s] = False
             self._rid[s] = None
             self._slot_req[s] = None
@@ -627,13 +654,15 @@ class ContinuousEngine:
                     keep.append(req)
             self._queue = keep
 
-    def _time_out(self, req: SlotRequest, *, admitted_at: float) -> None:
+    def _time_out(self, req: SlotRequest, *, admitted_at: float,
+                  first_token_at: float = 0.0) -> None:
         self.stats.n_timed_out += 1
         self._requeues.pop(req.rid, None)
         self._results[req.rid] = CompletedGeneration(
             rid=req.rid, tokens=np.zeros(0, np.int32), n_steps=0,
             prompt_len=self._padded_len(len(req.prompt)),
             finished_at=self._clock(), admitted_at=admitted_at,
+            first_token_at=first_token_at,
             failed="deadline exceeded", timed_out=True)
 
     def _abort_residents(self, reason: str) -> None:
@@ -708,55 +737,54 @@ class ContinuousEngine:
         the loop survives and keeps admitting.  After every control
         sync a health pass quarantines poisoned slots (device NaN/inf
         flags, no-progress watchdog) and a deadline pass cancels
-        expired requests, both BEFORE harvest."""
-        self._harvest()
-        if self._active.any():
-            # decode chunk first (async), then overlap the next
-            # admission groups' prefills with it; block only at the
-            # control sync
-            tr = self.tracer
-            t_chunk0 = tr.now()
-            try:
-                self.executor.decode_chunk()
-            except TransientFaultError as exc:
-                self.stats.n_exec_faults += 1
-                self._abort_residents(f"decode fault: {exc}")
-                return
-            self.stats.n_decode_chunks += 1
-            self.stats.n_decode_steps += self.sync_every
-            self._start_admissions()
-            self._sync()
-            # dispatch→post-sync wall of this K-step chunk (the prefills
-            # overlapped above render as nested engine-track spans)
-            tr.engine_span("decode_chunk", t_chunk0, tr.now(),
-                           steps=self.sync_every)
-            self._check_health()
-            self._expire_residents()
+        expired requests, both BEFORE harvest.  The whole iteration is
+        the ``engine.step`` span."""
+        tr = self.tracer
+        with tr.span("engine.step"):
             self._harvest()
-        else:
-            self._start_admissions()
-            if self._dirty:
+            if self._active.any():
+                # decode chunk first (async), then overlap the next
+                # admission groups' prefills with it; block only at the
+                # control sync
+                try:
+                    with tr.span("engine.decode_dispatch",
+                                 steps=self.sync_every):
+                        self.executor.decode_chunk()
+                except TransientFaultError as exc:
+                    self.stats.n_exec_faults += 1
+                    self._abort_residents(f"decode fault: {exc}")
+                    return
+                self.stats.n_decode_chunks += 1
+                self.stats.n_decode_steps += self.sync_every
+                self._start_admissions()
                 self._sync()
                 self._check_health()
                 self._expire_residents()
                 self._harvest()
-            elif self._queue:
-                self._expire_residents()
-                if not self._free and self.n_resident == 0:
-                    # every slot is quarantined: nothing can ever be
-                    # admitted — fail the queue transiently rather than
-                    # spinning forever (callers see resolved requests)
-                    while self._queue:
-                        req = self._queue.popleft()
-                        now = self._clock()
-                        self._requeues.pop(req.rid, None)
-                        self._results[req.rid] = CompletedGeneration(
-                            rid=req.rid, tokens=np.zeros(0, np.int32),
-                            n_steps=0,
-                            prompt_len=self._padded_len(len(req.prompt)),
-                            finished_at=now, admitted_at=now,
-                            failed="all slots quarantined",
-                            transient=True)
+            else:
+                self._start_admissions()
+                if self._dirty:
+                    self._sync()
+                    self._check_health()
+                    self._expire_residents()
+                    self._harvest()
+                elif self._queue:
+                    self._expire_residents()
+                    if not self._free and self.n_resident == 0:
+                        # every slot is quarantined: nothing can ever be
+                        # admitted — fail the queue transiently rather than
+                        # spinning forever (callers see resolved requests)
+                        while self._queue:
+                            req = self._queue.popleft()
+                            now = self._clock()
+                            self._requeues.pop(req.rid, None)
+                            self._results[req.rid] = CompletedGeneration(
+                                rid=req.rid, tokens=np.zeros(0, np.int32),
+                                n_steps=0,
+                                prompt_len=self._padded_len(len(req.prompt)),
+                                finished_at=now, admitted_at=now,
+                                failed="all slots quarantined",
+                                transient=True)
 
     def poll(self) -> Dict[int, CompletedGeneration]:
         """Advance the engine by one ``step`` (when it has work) and

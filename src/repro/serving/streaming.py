@@ -105,17 +105,22 @@ class StreamHandle:
     # set when the gateway itself died (backend raised a non-transient
     # exception): result() re-raises it instead of returning an outcome
     error: Optional[BaseException] = None
-    # per-stage latency attribution (queue_wait/admission/retrieval/
-    # prefill/decode/harvest) — set at completion when tracing is on
+    # per-stage latency attribution (repro.obs.STAGES) — set at
+    # completion when tracing is on
     breakdown: Optional[RequestBreakdown] = None
     _event: threading.Event = field(default_factory=threading.Event)
     # gateway-internal: routed action + whether burn forced the refusal
     _action: int = -1
     _forced: bool = False
-    # gateway-internal trace stamps: popped off the arrival queue /
-    # handed to the backend stream (gateway-clock seconds; 0 = not yet)
+    # gateway-internal trace stamps: enqueued under the lock / popped
+    # off the arrival queue / handed to the backend stream (gateway-
+    # clock seconds; 0 = not yet), the routing interval of its batch
+    # and its backend request id
+    _enq_t: float = 0.0
     _pop_t: float = 0.0
     _dispatch_t: float = 0.0
+    _route_t: Optional[Tuple[float, float]] = None
+    _rid: Optional[int] = None
 
     def done(self) -> bool:
         return self._event.is_set()
@@ -139,6 +144,8 @@ class StreamHandle:
 
     @property
     def first_token_ms(self) -> Optional[float]:
+        """Arrival to the first sync that showed the first token (for
+        an immediate outcome, to its completion)."""
         if self.first_token_t is None:
             return None
         return (self.first_token_t - self.arrival_t) * 1e3
@@ -256,7 +263,9 @@ class AsyncGateway(Gateway):
                 self._arrivals.append(handle)
                 # root span opens at arrival: queueing delay is part of
                 # what the trace must attribute (tracer state is only
-                # ever touched under the pump lock)
+                # ever touched under the pump lock); arrival to here is
+                # the time this thread waited for the lock
+                handle._enq_t = self.tracer.now()
                 self.tracer.begin_request(request.qid, now)
         if failed is not None:
             # a dead gateway must not hand out handles that never
@@ -373,9 +382,11 @@ class AsyncGateway(Gateway):
         h._forced = forced
         tr = self.tracer
         try:
-            rid, immediate = self.backend.stream_submit(
-                h.request.question, self.space[a],
-                deadline_at=self._deadline_at(h))
+            with tr.span("gateway.submit", qid=h.request.qid) as sp:
+                rid, immediate = self.backend.stream_submit(
+                    h.request.question, self.space[a],
+                    deadline_at=self._deadline_at(h))
+                sp.set(rid=rid)
         except TransientFaultError as exc:
             # dispatch stamp + adoption of any retrieval note the
             # backend recorded before faulting: the admission span must
@@ -393,6 +404,7 @@ class AsyncGateway(Gateway):
         # The backend doesn't know our qid (request ids are per-stream),
         # hence note→adopt rather than a direct mark.
         h._dispatch_t = tr.now()
+        h._rid = rid
         tr.adopt(h.request.qid)
         if immediate is not None:
             t = self.clock()
@@ -419,8 +431,11 @@ class AsyncGateway(Gateway):
             raise
 
     def _pump_once(self) -> int:
+        """One iteration under the gateway lock: the ``gateway.pump``
+        span, kept when it handled an event or stepped the engine."""
         n_events = 0
-        with self._lock:
+        tr = self.tracer
+        with self._lock, tr.span("gateway.pump") as pump:
             self._processing = []
             # 0) resubmit retries whose backoff has elapsed (already
             #    routed — they bypass admission and routing)
@@ -445,7 +460,6 @@ class AsyncGateway(Gateway):
             admitted: List[StreamHandle] = []
             now = self.clock()
             backlog = self.backend.stream_backlog + len(self._in_flight)
-            tr = self.tracer
             for h in batch:
                 h._pop_t = now
                 if self._should_shed(h, now, backlog + len(admitted)):
@@ -454,6 +468,8 @@ class AsyncGateway(Gateway):
                     # breakdown is pure queue_wait, stage sum == e2e
                     tr.mark(h.request.qid, "queue_wait",
                             h.arrival_t, now)
+                    tr.mark(h.request.qid, "lock_wait", h.arrival_t,
+                            min(max(h._enq_t, h.arrival_t), now))
                     h.breakdown = tr.finish_request(
                         h.request.qid, "shed", t=now)
                     self.budget.record_breakdown(h.breakdown)
@@ -466,7 +482,11 @@ class AsyncGateway(Gateway):
             # 2) route the admitted batch (adaptive refusal cap included)
             if admitted:
                 reqs = [h.request for h in admitted]
-                decision, cap = self._route(reqs)
+                with tr.span("gateway.route", n=len(reqs)) as sp:
+                    decision, cap = self._route(reqs)
+                route_t = (sp.t0, sp.t1)
+                for h in admitted:
+                    h._route_t = route_t
                 if cap is not None and "refusal_cap" in decision.constraints:
                     self.stats.refusal_cap_history.append(cap)
                 self.stats.decisions.append(decision)
@@ -484,23 +504,33 @@ class AsyncGateway(Gateway):
             # 4) advance the engine and harvest; transient completions
             #    (executor fault, circuit denial) go back through the
             #    retry budget instead of straight to the caller
-            for comp in self.backend.stream_poll():
-                h = self._in_flight.pop(comp.rid, None)
-                if h is None:
-                    continue
-                out = comp.outcome
-                if (getattr(out, "transient", False)
-                        and not getattr(out, "timed_out", False)
-                        and self._try_schedule_retry(h, comp.finished_at)):
-                    n_events += 1
-                    continue
-                self._account_stream(h, h._action, out,
-                                     comp.finished_at, comp.admitted_at,
-                                     forced=h._forced)
-                n_events += 1
+            comps = self.backend.stream_poll()
+            if comps:
+                with tr.span("gateway.account", n=len(comps)):
+                    for comp in comps:
+                        n_events += self._complete_one(comp)
             self._sync_cache_stats()
             self._processing = []
+            pump.set(n_events=n_events)
+            if not (n_events or pump.n_children):
+                pump.drop()      # an idle poll: nothing to record
         return n_events
+
+    def _complete_one(self, comp) -> int:
+        """Account one backend completion (or schedule its retry).
+        Returns the events handled (0 for a rid this gateway does not
+        own).  Lock held."""
+        h = self._in_flight.pop(comp.rid, None)
+        if h is None:
+            return 0
+        out = comp.outcome
+        if (getattr(out, "transient", False)
+                and not getattr(out, "timed_out", False)
+                and self._try_schedule_retry(h, comp.finished_at)):
+            return 1
+        self._account_stream(h, h._action, out, comp.finished_at,
+                             comp.first_token_at or None, forced=h._forced)
+        return 1
 
     def _fail(self, exc: BaseException) -> None:
         """The serving plane died (non-transient backend exception):
@@ -532,11 +562,12 @@ class AsyncGateway(Gateway):
                 h.request, h._action, f"gateway failed: {exc}"), now)
 
     def _account_stream(self, h: StreamHandle, a: int, out: ActionOutcome,
-                        finished_t: float, first_token_t: float, *,
-                        forced: bool) -> None:
+                        finished_t: float, first_token_t: Optional[float],
+                        *, forced: bool) -> None:
         """Per-request accounting with TRUE per-request latency
         (arrival -> completion, queueing included) — unlike the
-        closed-loop path's per-batch mean."""
+        closed-loop path's per-batch mean.  ``first_token_t`` is None
+        when the request produced no token."""
         lat_ms = (finished_t - h.arrival_t) * 1e3
         tr = self.tracer
         if tr.enabled:
@@ -546,7 +577,9 @@ class AsyncGateway(Gateway):
             # monotone order so a missing stamp (immediate refusal,
             # fault before dispatch) collapses its stage to zero width
             # instead of corrupting the tree — the top-level stage sum
-            # equals end-to-end latency by construction.
+            # equals end-to-end latency by construction.  lock_wait
+            # (arrival → enqueued) nests in queue_wait, route in
+            # admission.
             qid = h.request.qid
             t_acc = tr.now()
             arr = h.arrival_t
@@ -558,7 +591,11 @@ class AsyncGateway(Gateway):
             ft = first_token_t if first_token_t else disp
             ft = min(max(ft, disp), fin)
             tr.mark(qid, "queue_wait", arr, pop)
-            tr.mark(qid, "admission", pop, disp)
+            tr.mark(qid, "lock_wait", arr, min(max(h._enq_t, arr), pop))
+            tr.mark(qid, "admission", pop, disp, rid=h._rid)
+            if h._route_t is not None:
+                tr.mark(qid, "route", min(max(h._route_t[0], pop), disp),
+                        min(max(h._route_t[1], pop), disp))
             tr.mark(qid, "prefill", disp, ft)
             tr.mark(qid, "decode", ft, fin)
             # harvest: the completion sat in the engine's done list

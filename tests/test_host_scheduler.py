@@ -264,3 +264,40 @@ def test_nonstrict_reject_with_slots_resident_mid_flight():
     assert done[r1].failed
     assert list(done[r0].tokens) == expected(arith_gen(p0), 6)
     assert list(done[r2].tokens) == expected(arith_gen(p1), 6)
+
+
+def test_first_token_stamp_is_first_sync_showing_a_token():
+    """``first_token_at`` is the engine clock when the first control
+    sync after a request's admission returned (the first sync that
+    shows ``gen >= 1`` for its slot), not the prefill dispatch; and
+    ``admitted_at <= first_token_at <= finished_at``."""
+    eng, fake = make_engine(num_slots=2, sync_every=2, prefill_batch=1)
+    t = [0.0]
+    eng._clock = lambda: t[0]
+    admit, sync = fake.admit, fake.sync_control
+    admits, syncs = [], []       # (t, slots); (t after the sync, gen)
+
+    def rec_admit(tokens, slot_idx, limits):
+        t[0] += 0.25
+        admits.append((t[0], [int(s) for s in slot_idx]))
+        return admit(tokens, slot_idx, limits)
+
+    def rec_sync():
+        t[0] += 1.0              # the sync blocks on the device
+        out = sync()
+        syncs.append((t[0], out[1].copy()))
+        return out
+
+    fake.admit, fake.sync_control = rec_admit, rec_sync
+    prompts = _prompts([3, 6, 2, 9, 4])
+    outs = eng.generate_many(prompts, max_new_tokens=6)
+    assert len(admits) == 5
+    for (t_adm, slots), o in zip(admits, outs):
+        slot = slots[0]
+        want = next(ts for ts, gen in syncs
+                    if ts > t_adm and gen[slot] >= 1)
+        assert o.first_token_at == want
+        assert o.admitted_at < o.first_token_at <= o.finished_at
+    # a request that decodes past its first chunk finishes later still
+    assert any(o.first_token_at < o.finished_at for o in outs)
+    assert not eng._first_token_at          # no stamp outlives its slot

@@ -19,7 +19,7 @@ import pytest
 
 from repro.core.config import RouterConfig, TestbedConfig
 from repro.core.offline_log import build_testbed
-from repro.obs import (KINDS, NULL_TRACER, TOP_LEVEL, Histogram,
+from repro.obs import (KINDS, NULL_TRACER, STAGES, TOP_LEVEL, Histogram,
                        MetricsRegistry, NullTracer, RequestBreakdown,
                        StageAttribution, Tracer)
 from repro.routing import FixedPolicy, SimulatorBackend
@@ -247,9 +247,11 @@ def test_tracer_problems_catch_malformed_trees():
 
 
 def test_tracer_chrome_trace_export():
-    tr = Tracer(lambda: 0.0)
+    tr = Tracer(lambda: 0.0, annotate=None)
     _finish_simple(tr)
-    tr.engine_span("decode_chunk", 0.004, 0.008, steps=4)
+    with tr.span("engine.step"):
+        with tr.span("engine.decode_dispatch", steps=4):
+            pass
     data = json.loads(tr.chrome_trace_json(indent=1))
     events = data["traceEvents"]
     assert data["displayTimeUnit"] == "ms"
@@ -258,7 +260,9 @@ def test_tracer_chrome_trace_export():
                                  "problems": []}
     x = [e for e in events if e["ph"] == "X"]
     meta = [e for e in events if e["ph"] == "M"]
-    assert len(meta) == 2                   # engine + requests tracks
+    # host + requests processes, one named track per host thread
+    assert sorted(e["name"] for e in meta) == [
+        "process_name", "process_name", "thread_name"]
     assert all(e["dur"] >= 0 and e["ts"] >= 0 for e in x)
     root = [e for e in x if e["name"] == "request[completed]"]
     assert len(root) == 1 and root[0]["pid"] == 1
@@ -268,8 +272,12 @@ def test_tracer_chrome_trace_export():
             assert e["ts"] >= root[0]["ts"] - 1e-6
             assert (e["ts"] + e["dur"]
                     <= root[0]["ts"] + root[0]["dur"] + 1e-6)
-    eng = [e for e in x if e["pid"] == 0]
-    assert len(eng) == 1 and eng[0]["args"]["steps"] == 4
+    host = {e["name"]: e for e in x if e["pid"] == 0}
+    assert set(host) == {"engine.step", "engine.decode_dispatch"}
+    step, chunk = host["engine.step"], host["engine.decode_dispatch"]
+    assert step["args"]["parent"] == 0
+    assert chunk["args"]["parent"] == step["args"]["sid"] > 0
+    assert chunk["args"]["steps"] == 4 and chunk["tid"] == step["tid"]
 
 
 def test_tracer_sampling_bounds_memory():
@@ -289,7 +297,8 @@ def test_null_tracer_is_inert():
     NULL_TRACER.mark(1, "decode", 0.0, 1.0)
     NULL_TRACER.note("retrieval", 0.0, 1.0)
     NULL_TRACER.adopt(1)
-    NULL_TRACER.engine_span("prefill_dispatch", 0.0, 1.0)
+    with NULL_TRACER.span("engine.prefill_dispatch"):
+        pass
     assert NULL_TRACER.finish_request(1, "completed") is None
     assert NULL_TRACER.stage_percentiles() == {}
     assert NULL_TRACER.problems() == []
@@ -371,7 +380,7 @@ def test_traced_run_trees_well_formed_and_export_parses(traced_run):
     data = json.loads(tr.chrome_trace_json())
     assert len([e for e in data["traceEvents"] if e["ph"] == "X"]) > 0
     pct = tr.stage_percentiles()
-    assert set(pct) <= set(TOP_LEVEL) | {"retrieval", "e2e"}
+    assert set(pct) <= set(STAGES) | {"e2e"}
     assert pct["e2e"]["n"] == 80            # every terminal kind counted
     # LoadReport picked the stages table up
     assert rep.stages == pct
@@ -388,7 +397,7 @@ def test_traced_run_metrics_and_attribution(traced_run):
     report = gw.budget.report_dict()
     att = report.get("latency_attribution")
     assert att and att["n"] > 0
-    assert att["dominant_stage"] in set(TOP_LEVEL) | {"retrieval"}
+    assert att["dominant_stage"] in set(STAGES)
 
 
 def test_healthy_path_parity_with_tracing_disabled(testbed):
