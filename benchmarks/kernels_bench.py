@@ -76,6 +76,7 @@ def main() -> dict:
     # paged flash decode: the same KV content laid out as a page pool +
     # block table, at several page sizes, vs the dense kernel above
     from repro.kernels import paged_flash_decode
+    from repro.kernels.flash_decode import pages_per_block
     dense_out = flash_decode(q, kc, vc, lens)
     for ps in (16, 32, 64):
         MB = L // ps
@@ -92,9 +93,11 @@ def main() -> dict:
             "shape": f"S{S}xL{L}xH{H}xD{D}",
             "matches_dense": bool(jnp.allclose(dense_out, paged_out,
                                                rtol=1e-5, atol=1e-5)),
-            # per-tile VMEM: one query row + one K page + one V page +
+            # VMEM: two buffers of a block of ppb pages of K and of V +
             # accumulator + (m, l) running stats
-            "vmem_tile_bytes": (D + 2 * ps * D + D + 2) * 4}
+            "vmem_tile_bytes": (2 * 2 * pages_per_block(ps, Hkv, 2 * D, 4,
+                                                        MB) * ps * Hkv * D
+                                + H * D + 2 * H) * 4}
 
     save_artifact("kernels_bench", out)
     for k, v in out.items():

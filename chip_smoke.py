@@ -229,6 +229,7 @@ def check_kernels(model_cfg):
     import numpy as np
 
     from repro.kernels import flash_decode, paged_flash_decode, ref
+    from repro.kernels.flash_decode import pages_per_block
 
     B, H, Hkv, D = (NUM_SLOTS, model_cfg.n_heads, model_cfg.n_kv_heads,
                     model_cfg.head_dim)
@@ -257,9 +258,11 @@ def check_kernels(model_cfg):
                                np.asarray(want), rtol=KERNEL_TOL,
                                atol=KERNEL_TOL)
     err_d = float(jnp.max(jnp.abs(got_d.astype(f32) - want)))
+    ppb = pages_per_block(page_size, Hkv, 2 * D, kp.dtype.itemsize, MB)
     print(f"# kernels vs float32 oracle at B={B} H={H} Hkv={Hkv} D={D} "
-          f"pages={NP}x{page_size}: paged max|err| {err:.2e}, dense "
-          f"max|err| {err_d:.2e} (tolerance {KERNEL_TOL})")
+          f"pages={NP}x{page_size}, {ppb} pages a grid step: paged "
+          f"max|err| {err:.2e}, dense max|err| {err_d:.2e} (tolerance "
+          f"{KERNEL_TOL})")
 
 
 def check_shards(backend, model_cfg, mp: int):

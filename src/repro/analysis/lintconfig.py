@@ -33,8 +33,10 @@ DEFAULT_DIM_BINDINGS: Dict[str, int] = {
     "D": 256, "Dv": 256, "block_q": 128, "block_kv": 128,
     # decode: query heads per kv head (largest GQA group shipped: 8)
     "G": 8,
-    # paged decode: largest shipping page size
-    "ps": 64,
+    # paged decode: largest shipping page size; query and kv heads of
+    # the widest shipped GQA config; pages per block at those widths
+    # (pages_per_block: one page of 64 x 8 x 512 f32 is 1 MiB)
+    "ps": 64, "H": 64, "Hkv": 8, "ppb": 1,
     # dense retrieval: 128-aligned hashed-n-gram embedding, k<=64
     "E": 128, "block_d": 128, "k": 64,
     # bm25 hashed vocab tile

@@ -21,14 +21,18 @@ list, or from ``options["operand_dtypes"]`` overrides (e.g. int8 KV).
 
 ``PrefetchScalarGridSpec(num_scalar_prefetch=N, ...)`` is understood:
 the first N invocation operands are scalar-prefetch (SMEM) and carry no
-VMEM blocks, so in_specs align with operands[N:].
+VMEM blocks, so in_specs align with operands[N:].  A BlockSpec with
+``memory_space=pl.ANY`` (or HBM / SMEM) stays out of VMEM and counts
+nothing: a kernel that gathers such an operand with its own DMAs
+declares the destination as a ``pltpu.VMEM`` scratch, and that is
+where its bytes are counted.  Only ``VMEM`` scratch shapes count;
+semaphores and SMEM scratch do not live in VMEM.
 
 The second sub-check is the **masked tail**: a grid axis that does not
 divide the array needs either an in-kernel ``broadcasted_iota`` bounds
-mask (followed transitively through local kernel helpers — the paged
-decode kernel delegates to the dense one) or an explicit divisibility
-``assert x % block == 0`` in the wrapper.  A pallas_call with neither
-reads garbage out of the last partial tile.
+mask (followed transitively through local kernel helpers) or an
+explicit divisibility ``assert x % block == 0`` in the wrapper.  A
+pallas_call with neither reads garbage out of the last partial tile.
 
 The extraction/estimation helpers are import-stable API — the VMEM
 tests drive them directly against hand-computed block-shape math.
@@ -211,8 +215,26 @@ def dtype_bytes(expr: Optional[ast.AST],
     return DTYPE_BYTES.get(name, 4)
 
 
+#: memory spaces a BlockSpec or scratch may name that are not VMEM
+_NOT_VMEM = ("ANY", "HBM", "SMEM", "SEMAPHORE")
+
+
+def _space(call: ast.Call) -> str:
+    """Last component of a spec's ``memory_space`` (or "")."""
+    space = _kw(call, "memory_space")
+    return (dotted_name(space) or "").rsplit(".", 1)[-1] if space else ""
+
+
+def _in_vmem_scratch(call: ast.Call) -> bool:
+    """A scratch entry that occupies VMEM: ``pltpu.VMEM(shape, dtype)``
+    (semaphores and ``SMEM`` scratch do not)."""
+    return (dotted_name(call.func) or "").rsplit(".", 1)[-1] == "VMEM"
+
+
 def _block_bytes(spec: ast.Call, bindings: Dict[str, int],
                  nbytes: int) -> int:
+    if _space(spec) in _NOT_VMEM:
+        return 0
     n = 1
     for dim in _shape_elems(spec):
         n *= eval_dim(dim, bindings)
@@ -251,7 +273,7 @@ def estimate_site(site: PallasSite, *,
         out_b += _block_bytes(spec, bindings,
                               dtype_bytes(dt, odt, default_dtype))
     scr_b = 0
-    for scr in site.scratch_shapes:
+    for scr in filter(_in_vmem_scratch, site.scratch_shapes):
         dt = scr.args[1] if len(scr.args) > 1 else _kw(scr, "dtype")
         scr_b += _block_bytes(scr, bindings,
                               dtype_bytes(dt, odt, default_dtype))

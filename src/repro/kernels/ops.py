@@ -173,10 +173,14 @@ def paged_flash_decode(q, k_pages, v_pages, table, lengths):
     q: (B, H, D) — one query per slot; k/v_pages: (num_pages,
     page_size, Hkv, D[v]) — the executor's global page pools; table:
     (B, max_blocks) int32 page ids per slot; lengths: (B,) valid kv
-    length (>= 1).  Returns (B, H, Dv).  Pools transpose to
-    kv-head-major (page-local — never gathered to a contiguous row);
-    table entries clamp into range, and the kernel never reads blocks
-    past a slot's length.
+    length (>= 1).  Returns (B, H, Dv).  The pools go to the kernel as
+    they lie, never transposed or gathered: it copies each page's
+    contiguous ``(page_size, Hkv, D)`` slab with its own DMAs, a block
+    of ``ppb`` pages per grid step into double-buffered VMEM, the next
+    block's copies in flight while this one is computed; ``ppb`` comes
+    from the shapes (about 1 MiB of K plus V: 16 pages at Qwen1.5-32B's
+    widths; ``flash_decode.pages_per_block``).  Table entries clamp
+    into range, and the kernel never reads pages past a slot's length.
     """
     shards = _decode_shards(q.shape[0], k_pages.shape[2], k_pages.shape[0])
 
@@ -187,9 +191,7 @@ def paged_flash_decode(q, k_pages, v_pages, table, lengths):
             # this shard holds pool pages [i * NP, (i + 1) * NP): the
             # allocator keeps a slot's pages on the shard owning the slot
             tab = tab - jax.lax.axis_index(shards[1]) * NP
-        kf = k_pages.transpose(0, 2, 1, 3)        # (NP, Hkv, ps, D)
-        vf = v_pages.transpose(0, 2, 1, 3)
-        return paged_flash_decode_pallas(q, kf, vf,
+        return paged_flash_decode_pallas(q, k_pages, v_pages,
                                          jnp.clip(tab, 0, NP - 1),
                                          jnp.maximum(lengths, 1),
                                          interpret=_interpret())

@@ -13,7 +13,7 @@ import pytest
 
 from repro.kernels import flash_decode, paged_flash_decode
 from repro.kernels import ref
-from repro.kernels.flash_decode import flash_decode_pallas
+from repro.kernels.flash_decode import flash_decode_pallas, pages_per_block
 
 
 def _key(i):
@@ -99,17 +99,56 @@ def _paged_case(B, MB, ps, H, Hkv, Dh, seed=0):
     return q, kp, vp, table, lens
 
 
+def _ppb(ps, Hkv, Dh):
+    """The kernel's pages per grid step for float32 pools, unclamped."""
+    return pages_per_block(ps, Hkv, 2 * Dh, 4, 1 << 30)
+
+
+# Cases with MB = 0 take MB = 2 * ppb + 1 from the shapes, so the table
+# spans three blocks and is not a multiple of ppb.  Lengths:
+#   spread - evenly spaced from 1 to MB * ps;
+#   edges  - 1, ps, ppb * ps, ppb * ps + 1 and MB * ps - 1 (block and
+#            page boundaries);
+#   idle   - the engine's idle slots (length 0, table rows all out of
+#            range) between live ones;
+#   stale  - table entries past each slot's length point at pages of
+#            other slots or out of range.
 @pytest.mark.parametrize("ps", [8, 16, 32])
-@pytest.mark.parametrize("B,MB,H,Hkv,Dh", [
-    (3, 4, 4, 2, 32),          # GQA grouping
-    (2, 6, 2, 2, 64),
-    (1, 2, 4, 1, 32),          # MQA, tiny table
+@pytest.mark.parametrize("B,MB,H,Hkv,Dh,lens", [
+    (3, 4, 4, 2, 32, "spread"),     # GQA grouping
+    (2, 6, 2, 2, 64, "spread"),
+    (1, 2, 4, 1, 32, "spread"),     # MQA, tiny table
+    (5, 0, 40, 8, 128, "edges"),    # G = 5, Qwen1.5-32B's grouping
+    (5, 0, 32, 4, 128, "idle"),     # G = 8
+    (4, 0, 8, 8, 128, "stale"),     # G = 1
 ])
-def test_paged_flash_decode_matches_ref(B, MB, H, Hkv, Dh, ps):
-    q, kp, vp, table, lens = _paged_case(B, MB, ps, H, Hkv, Dh)
+def test_paged_flash_decode_matches_ref(B, MB, H, Hkv, Dh, lens, ps):
+    ppb = _ppb(ps, Hkv, Dh)
+    MB = MB or 2 * ppb + 1
+    q, kp, vp, table, spread = _paged_case(B, MB, ps, H, Hkv, Dh)
+    NP = kp.shape[0]
+    live = np.ones(B, bool)
+    if lens == "spread":
+        lens = spread
+    elif lens == "edges":
+        lens = jnp.array([1, ps, ppb * ps, ppb * ps + 1, MB * ps - 1],
+                         jnp.int32)
+    elif lens == "idle":
+        live = np.array([1, 0, 1, 0, 1], bool)
+        lens = jnp.array([ppb * ps + 1, 0, MB * ps, 0, 3], jnp.int32)
+        table = jnp.where(jnp.asarray(live)[:, None], table, NP + 7)
+    else:
+        lens = jnp.array([ps + 1, 2 * ppb * ps - 1, 1, MB * ps], jnp.int32)
+        used = (np.asarray(lens)[:, None] + ps - 1) // ps
+        past = np.arange(MB)[None, :] >= used
+        other = np.roll(np.asarray(table), 1, axis=0)
+        junk = np.where(np.arange(B)[:, None] % 2, other, NP + 100)
+        table = jnp.asarray(np.where(past, junk, np.asarray(table)))
     got = paged_flash_decode(q, kp, vp, table, lens)
     want = ref.paged_flash_decode_ref(q, kp, vp, table, lens)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got)[live],
+                               np.asarray(want)[live],
                                rtol=1e-5, atol=1e-5)
 
 

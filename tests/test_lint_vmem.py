@@ -22,6 +22,7 @@ from repro.analysis.lintconfig import (DEFAULT_CONFIG,
 from repro.analysis.rules.pallas_vmem import (UnboundDim, estimate_site,
                                               extract_sites)
 from repro.analysis.walker import import_table, run_lint
+from repro.kernels.flash_decode import pages_per_block
 
 KERNELS = Path(__file__).resolve().parents[1] / "src" / "repro" / "kernels"
 
@@ -66,7 +67,17 @@ def test_flash_decode_int8_kv():
 
 
 # -- paged flash decode: PrefetchScalarGridSpec, lengths + table are
-#    scalar-prefetch ----------------------------------------------------
+#    scalar-prefetch (SMEM); the page pools stay in HBM
+#    (memory_space=pl.ANY) and count nothing: the kernel's own DMAs
+#    gather a block of ppb pages into two double-buffered VMEM scratches
+#    (2, ppb*ps*Hkv, D|Dv).  q block (1,H,D), out block (1,H,Dv);
+#    scratch (H,1)+(H,1)+(H,Dv) f32; the DMA semaphores and the SMEM
+#    buffer index hold no VMEM.  Served: H=40, Hkv=8, D=Dv=128, bf16,
+#    ppb = 1 MiB // (ps*Hkv*(D+Dv)*2 B), so a block is 2048 rows of 128
+#    whatever the page size ----------------------------------------------
+
+SERVED = {"H": 40, "Hkv": 8, "D": 128, "Dv": 128}
+BF16 = {"q": "bfloat16", "k_pages": "bfloat16", "v_pages": "bfloat16"}
 
 
 def test_paged_flash_decode_skips_scalar_prefetch_operand():
@@ -74,28 +85,40 @@ def test_paged_flash_decode_skips_scalar_prefetch_operand():
     assert site.num_scalar_prefetch == 2
     assert site.operands[:2] == ["lens", "table"]   # SMEM, not estimated
     assert site.operands[2:] == ["q", "k_pages", "v_pages"]
+    est = estimate_site(site, bindings={**SERVED, "ps": 16, "ppb": 16},
+                        operand_dtypes=BF16)
+    # the two pools are memory_space=ANY: q's block is the only in-block
+    assert est.in_bytes == 40 * 128 * 2 == 10240
 
 
-@pytest.mark.parametrize("ps,expected_total", [
-    (16, (18944 + 2560) * 2 + 2600),   # in = (5*128+16*128*2)*4 = 18944
-    (32, (35328 + 2560) * 2 + 2600),   # in = (5*128+32*128*2)*4 = 35328
-    (64, (68096 + 2560) * 2 + 2600),   # in = (5*128+64*128*2)*4 = 68096
+@pytest.mark.parametrize("ps,ppb,expected_total", [
+    # in = out = 40*128*2 = 10240; K = V = 2*(ppb*ps*8)*128*2 = 1048576;
+    # scratch = 2*1048576 + (40+40)*4 + 40*128*4 = 2117952
+    (16, 16, (10240 + 10240) * 2 + 2117952),  # 16*16*8 = 2048 rows
+    (32, 8, (10240 + 10240) * 2 + 2117952),   # 8*32*8 = 2048 rows
+    (64, 4, (10240 + 10240) * 2 + 2117952),   # 4*64*8 = 2048 rows
 ])
-def test_paged_flash_decode_page_size_sweep(ps, expected_total):
+def test_paged_flash_decode_page_size_sweep(ps, ppb, expected_total):
+    assert pages_per_block(ps, 8, 256, 2, 1 << 20) == ppb
     site = site_by_kernel("flash_decode.py", "_paged_flash_decode_kernel")
-    est = estimate_site(site, bindings={"G": 5, "D": 128, "Dv": 128,
-                                        "ps": ps})
-    assert est.total_bytes == expected_total
+    est = estimate_site(site, bindings={**SERVED, "ps": ps, "ppb": ppb},
+                        operand_dtypes=BF16)
+    assert est.scratch_bytes == 2117952
+    assert est.total_bytes == expected_total == 2158912
 
 
 def test_paged_flash_decode_int8_kv_pages():
+    # int8 pools: ppb = 1 MiB // (64*8*256*1) = 8, 8*64*8 = 4096 rows;
+    # K = V = 2*4096*128*1 = 1048576 (the scratch takes the pools'
+    # dtype); q stays f32: in = out = 40*128*4 = 20480
+    assert pages_per_block(64, 8, 256, 1, 1 << 20) == 8
     site = site_by_kernel("flash_decode.py", "_paged_flash_decode_kernel")
     est = estimate_site(
-        site, bindings={"G": 5, "D": 128, "Dv": 128, "ps": 64},
+        site, bindings={**SERVED, "ps": 64, "ppb": 8},
         operand_dtypes={"k_pages": "int8", "v_pages": "int8"})
-    in_bytes = 5 * 128 * 4 + 64 * 128 + 64 * 128
-    assert est.in_bytes == in_bytes
-    assert est.total_bytes == (in_bytes + 2560) * 2 + 2600
+    assert est.in_bytes == 40 * 128 * 4 == 20480
+    assert est.scratch_bytes == 2 * 1048576 + (40 + 40) * 4 + 40 * 128 * 4
+    assert est.total_bytes == (20480 + 20480) * 2 + 2117952 == 2199872
 
 
 # -- dense_topk: in (block_q,E)+(block_d,E); out 2x(block_q,k) f32/i32;
