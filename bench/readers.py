@@ -2,7 +2,8 @@
 
 Every function takes the traced run's :class:`tracewin.Context` and
 returns a number, or ``None`` when the slice holds nothing to read.
-Shares are in percent.
+Shares are in percent.  Operations, bytes and the decode kernel's name
+are the cell's family's (``ctx.family``, ``family.py``).
 """
 from __future__ import annotations
 
@@ -11,12 +12,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-import flops
-
 DECODE = "decode_chunk"
 PREFILL = "prefill"
-# the Pallas paged flash-decode kernel as the trace names it
-PAGED_KERNEL = "paged_flash_decode"
 
 
 def span_p90(ctx, stage: str) -> Optional[float]:
@@ -83,18 +80,19 @@ def _chunks(ctx):
 def useful_flops(ctx) -> float:
     """FLOPs of the unpadded prompts prefilled and the tokens decoded
     in the slice, for the whole model."""
-    sh, k = ctx.shape, int(ctx.cfg["serving"]["sync_every"])
+    fam, sh = ctx.family, ctx.shape
+    k = int(ctx.cfg["serving"]["sync_every"])
     cap = int(ctx.mix["max_new_tokens"])
     total = 0.0
     for t, slots, padded, unpadded in ctx.admits:
         if ctx.t_a <= t < ctx.t_b:
-            total += sum(flops.prefill_flops(sh, int(u)) for u in unpadded
+            total += sum(fam.prefill_flops(sh, int(u)) for u in unpadded
                          if u > 0)
     for _, _, (active, gen, plen, ulen) in _chunks(ctx):
         for j in range(k):
             live = active & (gen + j < cap)
             for s in np.flatnonzero(live):
-                total += flops.decode_flops(sh, int(ulen[s] + gen[s] + j))
+                total += fam.decode_flops(sh, int(ulen[s] + gen[s] + j))
     return total
 
 
@@ -106,23 +104,24 @@ def mfu(ctx) -> Optional[float]:
 
 
 def paged_roofline(ctx) -> Optional[float]:
-    """Least time at peak HBM bandwidth for the pages each call must
-    read (the live slots' valid pages), over the kernel's device time,
-    on chip 0."""
-    sh = ctx.shape_chip
+    """Least time at peak HBM bandwidth for the bytes each call of the
+    family's decode kernel must move (the live slots' valid pages), over
+    the kernel's device time, on chip 0."""
+    fam, sh = ctx.family, ctx.shape_chip
     k = int(ctx.cfg["serving"]["sync_every"])
     ps = int(ctx.cfg["serving"]["page_size"])
     cap = int(ctx.mix["max_new_tokens"])
     bw = float(ctx.peak["hbm_bytes_per_s"])
     need = spent = 0.0
     for a, b, (active, gen, plen, _) in _chunks(ctx):
-        calls = [o for o in ctx.ops_in(0, a, b) if PAGED_KERNEL in o[0]]
+        calls = [o for o in ctx.ops_in(0, a, b)
+                 if fam.decode_kernel in o[0]]
         if len(calls) != k * sh["layers"]:
             continue
         for j in range(k):
             live = np.flatnonzero(active & (gen + j < cap))
             lens = [int(plen[s] + gen[s] + j) for s in live]
-            need += sh["layers"] * flops.paged_decode_bytes(sh, lens, ps)
+            need += sh["layers"] * fam.decode_bytes(sh, lens, ps)
         spent += sum(o[3] - o[2] for o in calls)
     if spent <= 0:
         return None

@@ -1,16 +1,16 @@
 """Plain references for what the timed path produced.
 
 * :func:`logit_gaps` — the model: a float32 forward pass at the
-  highest matmul precision, written from the published Qwen2
-  architecture (pre-norm RMSNorm, rotary embeddings over halves,
-  grouped-query attention with q/k/v biases, SwiGLU MLP, untied LM
-  head).  It runs one sequence and one layer at a time over the exact
-  token ids the engine received (PAD included) followed by the tokens
-  it served, and reads the weights the benchmark made, by name, in the
-  program's layout.  With ``control=True`` the same pass also runs with
-  every matrix product in float8 (e4m3, per-row activation and
-  per-column weight scales), the precision below the configuration's
-  bfloat16.
+  highest matmul precision, each layer as the configuration's family
+  (``families/<model_type>.py``) writes it from the published
+  architecture, then the final RMSNorm and the LM head.  It runs one
+  sequence and one layer at a time, in the program's layer order, over
+  the exact token ids the engine received (PAD included) followed by
+  the tokens it served, and reads the weights the benchmark made, by
+  name, in the program's layout; a leaf it would not read is refused.
+  With ``control=True`` the same pass also runs with every matrix
+  product in float8 (e4m3, per-row activation and per-column weight
+  scales), the precision below the configuration's bfloat16.
 * :class:`ExactBM25` — exact BM25 in float64 over the corpus text.
 * :func:`route_logits` — the router's state features and MLP in float64.
 * :func:`prompt_ids` — the prompt templates and the hashed tokenizer.
@@ -20,7 +20,6 @@ Nothing here imports the program.
 from __future__ import annotations
 
 import hashlib
-import math
 import re
 from functools import partial
 from typing import Dict, List, Sequence
@@ -28,7 +27,6 @@ from typing import Dict, List, Sequence
 import numpy as np
 
 HEAD_CHUNK = 16384       # vocabulary rows per LM-head block
-Q_CHUNK = 512            # query rows per attention block
 F8_MAX = 448.0           # largest float8_e4m3fn
 
 
@@ -65,38 +63,6 @@ def _rope(x, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _layer(lp, x, *, eps: float, theta: float, control: bool):
-    import jax
-    import jax.numpy as jnp
-    lp = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), lp)
-    at, ml = lp["attn"], lp["mlp"]
-    s, d = x.shape
-    _, h, dh = at["wq"].shape
-    hkv = at["wk"].shape[1]
-    hn = _rms(x, lp["ln1"], eps)
-    q = _mm(hn, at["wq"].reshape(d, h * dh), control).reshape(s, h, dh)
-    k = _mm(hn, at["wk"].reshape(d, hkv * dh), control).reshape(s, hkv, dh)
-    v = _mm(hn, at["wv"].reshape(d, hkv * dh), control).reshape(s, hkv, dh)
-    q = _rope(q + at["bq"], theta)
-    k = jnp.repeat(_rope(k + at["bk"], theta), h // hkv, axis=1)
-    v = jnp.repeat(v + at["bv"], h // hkv, axis=1)
-    outs = []
-    for c0 in range(0, s, Q_CHUNK):
-        qc = q[c0:c0 + Q_CHUNK]
-        sc = jnp.einsum("qhd,khd->hqk", qc, k,
-                        precision="highest") / math.sqrt(dh)
-        mask = np.arange(s)[None, :] <= np.arange(c0, c0 + len(qc))[:, None]
-        sc = jnp.where(jnp.asarray(mask)[None], sc, -jnp.inf)
-        p = jax.nn.softmax(sc, axis=-1)
-        outs.append(jnp.einsum("hqk,khd->qhd", p, v, precision="highest"))
-    att = jnp.concatenate(outs, 0).reshape(s, h * dh)
-    x = x + _mm(att, at["wo"].reshape(h * dh, d), control)
-    hn = _rms(x, lp["ln2"], eps)
-    ff = jax.nn.silu(_mm(hn, ml["w_gate"], control)) * _mm(
-        hn, ml["w_up"], control)
-    return x + _mm(ff, ml["w_down"], control)
-
-
 def _readout(x, xc, norm_w, head_w, served, *, eps: float):
     """Per position: the reference's best logit minus its logit of the
     served token; and, when ``xc`` (the control's hidden states) is
@@ -128,22 +94,92 @@ def _readout(x, xc, norm_w, head_w, served, *, eps: float):
     return rmax - rsv, rmax - r_at_c
 
 
-def logit_gaps(params, cfg: Dict, seqs: Sequence[np.ndarray],
+def _path(path) -> str:
+    return ".".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                    for k in path)
+
+
+def layer_order(params) -> List[tuple]:
+    """``(name, tree, i)`` of each layer in the program's order: the
+    prefix layers (``i`` None), then for each stacked block ``i`` its
+    period positions ``p0, p1, ...``."""
+    import jax
+    out = [(f"prefix.{j}", lp, None)
+           for j, lp in enumerate(params.get("prefix", []))]
+    blocks = params.get("blocks", {})
+    if blocks:
+        n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+        pos = sorted(blocks, key=lambda k: int(k[1:]))
+        out += [(f"blocks.{k}", blocks[k], i)
+                for i in range(n) for k in pos]
+    return out
+
+
+def _leaves_read(fn, tree, x, index) -> set:
+    """Paths of the leaves of ``tree`` that ``fn(tree, x, index=index)``
+    depends on."""
+    import jax
+    from jax.extend.core import Literal
+    jp = jax.make_jaxpr(lambda t, y, k: fn(t, y, index=k))(
+        tree, x, index).jaxpr
+    live = {v for v in jp.outvars if not isinstance(v, Literal)}
+    for eqn in reversed(jp.eqns):
+        if eqn.effects or any(v in live for v in eqn.outvars):
+            live.update(v for v in eqn.invars if not isinstance(v, Literal))
+    paths = [_path(p) for p, _ in jax.tree_util.tree_flatten_with_path(
+        tree)[0]]
+    return {p for p, v in zip(paths, jp.invars) if v in live}
+
+
+def check_read(params, cfg: Dict, family) -> None:
+    """Refuse a parameter tree with a leaf that the reference would not
+    read: the walk reads the embedding, the final norm, the LM head and
+    each layer of :func:`layer_order`, and the family's ``layer`` reads
+    the leaves its result depends on (traced once per place in the
+    tree, as every layer of one place computes alike)."""
+    import jax
+    import jax.numpy as jnp
+    d = params["embed"].shape[-1]
+    x = jax.ShapeDtypeStruct((2, d), jnp.float32)
+    index = jax.ShapeDtypeStruct((), jnp.int32)
+    read = {"embed", "final_norm", "lm_head"}
+    places = {}
+    for name, lp, i in layer_order(params):
+        places.setdefault(name, (lp, i))
+    for name, (lp, i) in places.items():
+        cut = 0 if i is None else 1
+        tree = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape[cut:], a.dtype), lp)
+        fn = partial(family.layer, cfg=cfg, control=False, name=name)
+        read |= {f"{name}.{p}" for p in _leaves_read(fn, tree, x, index)}
+    unread = [p for p in (_path(q) for q, _ in
+                          jax.tree_util.tree_flatten_with_path(params)[0])
+              if p not in read]
+    if unread:
+        raise ValueError(f"the reference reads no {unread} (family "
+                         f"{cfg.get('model_type')!r})")
+
+
+def logit_gaps(params, cfg: Dict, family, seqs: Sequence[np.ndarray],
                n_out: Sequence[int], *, control: bool = False,
                device=None) -> Dict[str, List[float]]:
     """``seqs[i]`` is a prompt as the engine received it followed by
     the ``n_out[i]`` tokens it served.  Returns the per-token gaps of
     the served tokens (``gaps``) and, with ``control``, of the float8
-    pass's own picks (``control_gaps``).  ``device`` pins the pass to
-    one device (the weights' layers are copied there one at a time)."""
+    pass's own picks (``control_gaps``).  ``family`` is the
+    configuration's (``family.py``), whose ``layer`` each layer runs,
+    told its place in the tree and its number in the walk.
+    ``device`` pins the pass to one device (the weights' layers are
+    copied there one at a time)."""
     import jax
     import jax.numpy as jnp
-    eps, theta = float(cfg["rms_norm_eps"]), float(cfg["rope_theta"])
-    blocks = params["blocks"]["p0"]
-    n_layers = blocks["ln1"].shape[0]
+    check_read(params, cfg, family)
+    eps = float(cfg["rms_norm_eps"])
+    order = layer_order(params)
     put = (lambda a: jax.device_put(a, device)) if device else (lambda a: a)
-    layer = jax.jit(partial(_layer, eps=eps, theta=theta, control=False))
-    layer_c = jax.jit(partial(_layer, eps=eps, theta=theta, control=True))
+    layer, layer_c = (jax.jit(partial(family.layer, cfg=cfg, control=c),
+                              static_argnames="name")
+                      for c in (False, True))
     readout = jax.jit(partial(_readout, eps=eps))
     embed_w, norm_w = put(params["embed"]), put(params["final_norm"])
     head_w = put(params["lm_head"]) if "lm_head" in params else embed_w
@@ -154,11 +190,13 @@ def logit_gaps(params, cfg: Dict, seqs: Sequence[np.ndarray],
         x = jnp.take(embed_w, put(jnp.asarray(ids)), axis=0).astype(
             jnp.float32)
         xc = x
-        for i in range(n_layers):
-            lp = jax.tree_util.tree_map(lambda a: put(a[i]), blocks)
-            x = layer(lp, x)
+        for k, (name, tree, i) in enumerate(order):
+            lp = jax.tree_util.tree_map(
+                lambda a: put(a if i is None else a[i]), tree)
+            index = put(np.int32(k))
+            x = layer(lp, x, name=name, index=index)
             if control:
-                xc = layer_c(lp, xc)
+                xc = layer_c(lp, xc, name=name, index=index)
             del lp
         # positions s-n-1 .. s-2 predict the n served tokens
         rd = slice(s - n - 1, s - 1)

@@ -4,10 +4,12 @@
         --trace <0|1>
 
 A cell of ``BENCHMARK.json`` names a configuration
-(``bench/configs/<config>.json``) and a traffic mix
-(``bench/mixes/<traffic>.json``); its correctness limits are in
-``bench/limits/<cell>.json`` and each per-layer metric is read by
-``bench/metrics/<metric>.py``.  Nothing here names a cell.
+(``bench/configs/<config>.json``, whose ``model_type`` names its
+architecture's family, ``bench/families/<model_type>.py``) and a
+traffic mix (``bench/mixes/<traffic>.json``); its correctness limits
+are in ``bench/limits/<cell>.json`` and each per-layer metric is read
+by ``bench/metrics/<metric>.py``.  Nothing here names a cell or a
+family.
 
 The run: check that JAX sees the cell's chips (a TPU; there is no CPU
 fallback), build the system from the seed, warm every shape the window
@@ -47,6 +49,7 @@ os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
 
 import repro  # noqa: E402,F401  (no result without the program)
 
+import family  # noqa: E402
 import reference  # noqa: E402
 import traffic  # noqa: E402
 from system import System  # noqa: E402
@@ -86,7 +89,7 @@ def load_cell(root: Path, workload: str) -> Dict:
                      else m["moves"] in names)]
     return {"name": workload, "cell": cell, "cfg": cfg, "mix": mix,
             "limits": limits, "e2e": e2e, "per_layer": per_layer,
-            "bench": bench}
+            "bench": bench, "family": family.of(bench, cfg)}
 
 
 def find_devices(chips: int, require_chip: bool):
@@ -357,7 +360,7 @@ def checks(win: Window, groups, cell: Dict, rng) -> Dict[str, Dict]:
         seqs.append(np.concatenate([s.prompts[rid], toks]))
         n_out.append(len(toks))
     win.sample_seqs, win.sample_n = seqs, n_out
-    gaps = reference.logit_gaps(s.params, cfg, seqs, n_out,
+    gaps = reference.logit_gaps(s.params, cfg, cell["family"], seqs, n_out,
                                 device=s.devices[0])["gaps"] if seqs else []
     return {
         **model_checks(gaps, lim),
@@ -422,7 +425,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     cache = enable_cache()
     rng = np.random.default_rng(np.random.SeedSequence(int(seed)))
     system = System(cell["cfg"], cell["mix"], int(seed), devices,
-                    trace=trace)
+                    cell["family"], trace=trace)
     if tamper is not None:
         tamper(system)
     system.warm()
@@ -493,8 +496,8 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
         # the float8 control in the program's place, judged by the same
         # checks and limits as the program
         ctl = reference.logit_gaps(
-            system.params, cell["cfg"], win.sample_seqs, win.sample_n,
-            control=True, device=devices[0])
+            system.params, cell["cfg"], cell["family"], win.sample_seqs,
+            win.sample_n, control=True, device=devices[0])
         result["control"] = ctl
         result["control_correct"] = passed(
             {**chk, **model_checks(ctl["control_gaps"], cell["limits"])})

@@ -15,7 +15,6 @@ the control syncs and the host spans around each layer's calls.
 """
 from __future__ import annotations
 
-import dataclasses
 import threading
 import time
 from typing import Dict, List, Optional
@@ -63,7 +62,7 @@ class RecordingRetriever:
 
 
 class System:
-    def __init__(self, cfg: Dict, mix: Dict, seed: int, devices, *,
+    def __init__(self, cfg: Dict, mix: Dict, seed: int, devices, family, *,
                  trace: bool):
         import jax
         from repro.configs import get_config
@@ -113,14 +112,13 @@ class System:
                              f"the mix's {want}")
         self.timings["router_s"] = time.perf_counter() - t
 
-        # the model, at the configuration's widths and cut depth
+        # the model, at the configuration's widths and cut, as its
+        # family (``family.py``) maps them onto the program's config
         t = time.perf_counter()
-        pcfg = dataclasses.replace(get_config(cfg["program_config"],
-                                              cfg.get("program_variant",
-                                                      "full")),
-                                   n_layers=cfg["num_hidden_layers"],
-                                   use_flash_decode=bool(sv["flash_decode"]))
-        self._check_widths(pcfg)
+        pcfg = family.program_config(
+            cfg, get_config(cfg["program_config"],
+                            cfg.get("program_variant", "full")))
+        family.check_widths(cfg, pcfg)
         self.model = build_model(pcfg)
         mesh, shardings = None, None
         if sv["mp"] > 1:
@@ -133,7 +131,7 @@ class System:
         else:
             shardings = jax.sharding.SingleDeviceSharding(devices[0])
         self.params = weights.make_params(self.model.param_shapes(), seed,
-                                          shardings)
+                                          family.LEAVES, shardings)
         jax.block_until_ready(self.params)
         self.timings["weights_s"] = time.perf_counter() - t
 
@@ -175,19 +173,6 @@ class System:
         self.admits: List[tuple] = []                # (t, slots, padded
         #                                              and real lengths)
         self._install_recorders()
-
-    def _check_widths(self, pcfg) -> None:
-        c = self.cfg
-        got = {"hidden_size": pcfg.d_model, "intermediate_size": pcfg.d_ff,
-               "num_attention_heads": pcfg.n_heads,
-               "num_key_value_heads": pcfg.n_kv_heads,
-               "vocab_size": pcfg.vocab_size, "rope_theta": pcfg.rope_theta,
-               "rms_norm_eps": pcfg.norm_eps,
-               "tie_word_embeddings": pcfg.tie_embeddings,
-               "torch_dtype": pcfg.dtype}
-        bad = {k: (v, c[k]) for k, v in got.items() if v != c[k]}
-        if bad or not pcfg.qkv_bias or pcfg.padded_vocab != pcfg.vocab_size:
-            raise ValueError(f"program config differs from the file: {bad}")
 
     def _install_recorders(self) -> None:
         eng, be = self.engine, self.backend

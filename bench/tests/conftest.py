@@ -30,6 +30,7 @@ def tiny_config() -> dict:
     return {
         "source": "smoke preset of the program (CPU tests only)",
         "program_config": "qwen1.5-32b", "program_variant": "smoke",
+        "model_type": "qwen2",
         "hidden_size": 256, "intermediate_size": 512,
         "num_attention_heads": 4, "num_key_value_heads": 4,
         "num_hidden_layers": 2, "vocab_size": 512, "rms_norm_eps": 1e-5,

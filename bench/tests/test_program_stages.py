@@ -22,9 +22,9 @@ class Stages:
 
 def context(breakdowns):
     return tracewin.Context(
-        cfg={}, mix={}, peak={}, chips=1, shape={}, shape_chip={},
-        t_a=0.0, t_b=1.0, clock_pc=0.0, trace=None, host=[], syncs=[],
-        admits=[], breakdowns=breakdowns)
+        cfg={}, mix={}, family=None, peak={}, chips=1, shape={},
+        shape_chip={}, t_a=0.0, t_b=1.0, clock_pc=0.0, trace=None, host=[],
+        syncs=[], admits=[], breakdowns=breakdowns)
 
 
 def stages(i):

@@ -9,10 +9,12 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+import family
 import readers
 import tracefile
 import tracewin
 
+BENCH = Path(__file__).resolve().parents[1]
 DATA = Path(__file__).resolve().parent / "data"
 
 
@@ -29,10 +31,10 @@ def recorded(request):
     raw = gzip.decompress((where / "trace.xplane.pb.gz").read_bytes())
     tr = tracefile.load(raw, ctx_json["clock_pc"])
     c = ctx_json
-    ctx = tracewin.Context(
-        cfg=c["cfg"], mix=c["mix"], peak=c["peak"], chips=c["chips"],
-        shape=tracewin.flops.shape(c["cfg"]),
-        shape_chip=tracewin.flops.shape(c["cfg"], per_chip=True),
+    cell = {"cfg": c["cfg"], "mix": c["mix"],
+            "family": family.of(BENCH, c["cfg"])}
+    ctx = tracewin.context(
+        cell, peak=c["peak"], chips=c["chips"],
         t_a=c["t_a"], t_b=c["t_b"], clock_pc=c["clock_pc"], trace=tr,
         host=[tuple(h) for h in c["host"]],
         syncs=[(t, np.array(a), np.array(g)) for t, a, g in c["syncs"]],

@@ -8,7 +8,6 @@ taken from a traced run.  Each per-layer metric of the cell is read by
 """
 from __future__ import annotations
 
-import importlib.util
 import shutil
 import tempfile
 import threading
@@ -19,7 +18,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-import flops
+import family
 import tracefile
 
 
@@ -60,6 +59,7 @@ class Context:
     """What a metric reader may read."""
     cfg: Dict
     mix: Dict
+    family: object                 # the cell's family (``family.py``)
     peak: Dict
     chips: int
     shape: Dict[str, int]          # whole model
@@ -96,6 +96,15 @@ class Context:
                 if t0 <= o[2] < t1 and o[1] not in tracefile.CONTAINERS]
 
 
+def context(cell: Dict, **fields) -> Context:
+    """A cell's context: its configuration, mix and family, and the
+    family's shapes of the whole model and of one chip's share."""
+    fam, cfg = cell["family"], cell["cfg"]
+    return Context(cfg=cfg, mix=cell["mix"], family=fam,
+                   shape=fam.shape(cfg),
+                   shape_chip=fam.shape(cfg, per_chip=True), **fields)
+
+
 def load_peaks(bench: Path, kind: str) -> Dict:
     import json
     table = json.loads((bench / "peaks.json").read_text())
@@ -106,11 +115,7 @@ def load_peaks(bench: Path, kind: str) -> Dict:
 
 
 def read_metric(bench: Path, name: str, ctx: Context) -> Optional[float]:
-    path = bench / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return family.by_name(bench, "metrics", name).read(ctx)
 
 
 def breakdown(ctx: Context) -> Dict:
@@ -170,11 +175,8 @@ def reduce(state: Dict, win, groups, cell: Dict):
     bds = [h.breakdown for q, h in win.handles.items()
            if q in answered and h.breakdown is not None]
     dev = win.sys.devices[0]
-    ctx = Context(cfg=cell["cfg"], mix=cell["mix"],
-                  peak=load_peaks(bench, dev.device_kind),
+    ctx = context(cell, peak=load_peaks(bench, dev.device_kind),
                   chips=len(system.devices),
-                  shape=flops.shape(cell["cfg"]),
-                  shape_chip=flops.shape(cell["cfg"], per_chip=True),
                   t_a=state["t_a"], t_b=state["t_b"],
                   clock_pc=state["clock_pc"], trace=tr,
                   host=list(system.host.spans), syncs=system.syncs,

@@ -8,7 +8,10 @@ do and greedy picks are not near-ties:
 
 * ``embed`` N(0, 1) (N(0, 1/hidden) when tied to the head); every
   projection N(0, 1/fan_in); ``lm_head`` N(0, 1/hidden);
-* RMSNorm weights 1 + N(0, 0.1^2); q/k/v biases N(0, 0.1^2).
+* RMSNorm weights 1 + N(0, 0.1^2); biases N(0, 0.1^2).
+
+The kind and fan-in of each leaf come from the family's ``LEAVES``
+(``family.py``).
 """
 from __future__ import annotations
 
@@ -17,17 +20,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-# leaf name -> (kind, index of its fan-in dim counted from the end)
-LEAVES = {
-    "embed": ("embed", None), "lm_head": ("proj", -1),
-    "final_norm": ("norm", None), "ln1": ("norm", None),
-    "ln2": ("norm", None),
-    "wq": ("proj", -3), "wk": ("proj", -3), "wv": ("proj", -3),
-    "wo": ("proj2", None), "bq": ("bias", None), "bk": ("bias", None),
-    "bv": ("bias", None), "w_gate": ("proj", -2), "w_up": ("proj", -2),
-    "w_down": ("proj", -2),
-}
 
 
 def seed_key(seed: int):
@@ -40,19 +32,20 @@ def _leaf_name(path) -> str:
     return str(getattr(path[-1], "key", getattr(path[-1], "name", "")))
 
 
-def check_layout(shapes) -> None:
-    """The reference reads the program's tree by these names; refuse a
-    layout it does not know (``lm_head`` is absent when the embedding
-    is tied)."""
+def check_layout(shapes, leaves) -> None:
+    """The reference reads the program's tree by the names of the
+    family's ``leaves``; refuse a layout it does not know (``lm_head``
+    is absent when the embedding is tied)."""
     names = {_leaf_name(p) for p, _ in
              jax.tree_util.tree_flatten_with_path(shapes)[0]}
-    if not set(LEAVES) - {"lm_head"} <= names <= set(LEAVES):
+    if not set(leaves) - {"lm_head"} <= names <= set(leaves):
         raise ValueError(f"unknown parameter layout: {sorted(names)}")
 
 
-def make_params(shapes, seed: int, shardings=None):
-    """``shapes``: the program's parameter ShapeDtypeStruct tree."""
-    check_layout(shapes)
+def make_params(shapes, seed: int, leaves, shardings=None):
+    """``shapes``: the program's parameter ShapeDtypeStruct tree;
+    ``leaves``: the family's leaf name -> (kind, fan-in dim)."""
+    check_layout(shapes, leaves)
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     # a tied embedding is also the LM head: scale it as the head
     tied = "lm_head" not in {_leaf_name(p) for p, _ in flat}
@@ -60,7 +53,7 @@ def make_params(shapes, seed: int, shardings=None):
     def build(key):
         out = []
         for i, (path, sds) in enumerate(flat):
-            kind, fan_dim = LEAVES[_leaf_name(path)]
+            kind, fan_dim = leaves[_leaf_name(path)]
             k = jax.random.fold_in(key, i)
             z = jax.random.normal(k, sds.shape, jnp.float32)
             if kind == "embed":
@@ -69,7 +62,7 @@ def make_params(shapes, seed: int, shardings=None):
                 w = 1.0 + 0.1 * z
             elif kind == "bias":
                 w = 0.1 * z
-            elif kind == "proj2":      # wo: (..., H, Dh, d), fan-in H*Dh
+            elif kind == "proj2":      # (..., H, Dh, d), fan-in H*Dh
                 w = z / math.sqrt(sds.shape[-3] * sds.shape[-2])
             else:
                 w = z / math.sqrt(sds.shape[fan_dim])
